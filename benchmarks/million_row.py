@@ -17,9 +17,10 @@ checks four things:
     and no (B, M, d)-sized gather — the same inspection
     tests/test_index_api.py runs at unit scale,
   * the MEASURED candidate-bytes ratio: valid (deduped) candidate slots
-    counted from the actual mask, int8 bytes = valid*(d+4) + B*k'*4d
-    (coarse rows + scales, then the fp32 shortlist) vs fp32 bytes =
-    valid*4d; gated at <= 0.30 (tools/bench_history.py, lower-is-better),
+    counted from the actual mask, int8 bytes = valid*(4w+4) + B*k'*4dp
+    (packed coarse rows + scales, then the fp32 shortlist; w and dp are
+    the kernels' row-store widths) vs fp32 bytes = valid*4dp; gated at
+    <= 0.30 (tools/bench_history.py, lower-is-better),
   * kernel parity on a query subsample, interpret mode: the HBM descent
     kernel bitwise-matches the multiprobe ref (and the SMEM kernel when
     the tree fits under the cap; probe 0 matches the single-probe ref),
@@ -54,7 +55,9 @@ from repro.data.synthetic import clustered_gaussians
 from repro.kernels import ref
 from repro.kernels.forest_traverse import SMEM_NODE_CAP, forest_traverse
 from repro.kernels.forest_traverse_hbm import forest_traverse_hbm
-from repro.kernels.fused_query_int8 import fused_gather_topk_int8
+from repro.kernels.fused_query import row_store
+from repro.kernels.fused_query_int8 import (fused_gather_topk_int8,
+                                            pack_int8_rows)
 
 ARTIFACT = os.path.join(os.path.dirname(__file__), "..", "artifacts",
                         "BENCH_million_row.json")
@@ -117,8 +120,8 @@ def _int8_parity(qdb, q, seed: int = 0) -> bool:
     ids = rng.integers(0, n, size=(q.shape[0], 128)).astype(np.int32)
     ids[rng.uniform(size=ids.shape) < 0.1] = -1
     ids = jnp.asarray(ids)
-    pd, pi = fused_gather_topk_int8(q, ids, qdb.q, qdb.scale, 10,
-                                    interpret=True)
+    pd, pi = fused_gather_topk_int8(q, ids, pack_int8_rows(qdb.q),
+                                    qdb.scale, 10, interpret=True)
     rd, ri = ref.fused_gather_topk_int8_ref(q, ids, qdb.q, qdb.scale, 10)
     ids_ok = bool((np.asarray(pi) == np.asarray(ri)).all())
     d_ok = bool(np.allclose(np.asarray(pd), np.asarray(rd), rtol=2e-5,
@@ -161,8 +164,12 @@ def run(n: int, d: int, n_trees: int, capacity: int, n_probes: int, b: int,
     valid = int(np.asarray(mask_duplicates(cand_ids, mask)).sum())
     m = int(cand_ids.shape[1])
     kp = min(expand * k, m)
-    int8_bytes = valid * (d + 4) + b * kp * 4 * d
-    fp32_bytes = valid * 4 * d
+    # bytes per DMA'd row, from the kernels' row stores: the packed int8
+    # row (+ its 4-byte scale) vs the lane-padded fp32 row
+    int8_row = 4 * pack_int8_rows(qdb.q[:1]).shape[-1] + 4
+    fp32_row = 4 * row_store(x[:1]).shape[-1]
+    int8_bytes = valid * int8_row + b * kp * fp32_row
+    fp32_bytes = valid * fp32_row
     bytes_ratio = int8_bytes / fp32_bytes
 
     # --- zero-fallback inspection of the traced mode="pallas" program

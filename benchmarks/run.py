@@ -28,6 +28,8 @@ def main() -> None:
                         "fused,frontier,build,roof,million,serving,"
                         "filtered,schedule,autoscale")
     args = p.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     fast = not args.paper_scale
     only = set(args.only.split(",")) if args.only else None
 

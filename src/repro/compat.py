@@ -1,10 +1,7 @@
-"""Version compatibility shims for the jax API surface this repo spans.
+"""The few jax API spellings the codebase routes through one place.
 
-The codebase targets the modern jax API (``jax.shard_map``,
-``jax.sharding.AxisType``, ``pltpu.CompilerParams``) but must also run on the
-pinned 0.4.x toolchain in the CI container, where those names either live
-under ``jax.experimental`` or carry their older spelling.  Every API-drift
-branch lives here so the rest of the code imports one canonical name.
+Written for the installed jax (0.9): ``jax.shard_map`` with ``check_vma``,
+``jax.make_mesh`` with explicit axis types, ``pltpu.CompilerParams``.
 """
 from __future__ import annotations
 
@@ -15,41 +12,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` on new jax, ``jax.experimental.shard_map`` on 0.4.x.
-
-    ``check_vma`` maps onto the old API's ``check_rep`` (same replication
-    check, renamed upstream).
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:
-            # mid-window jax (~0.5-0.6): top-level shard_map, old kwarg name
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` over ``mesh``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Auto-typed device mesh on both old and new jax.
-
-    New jax wants ``axis_types=(AxisType.Auto, ...)`` for shard_map +
-    tracing-time collectives; old jax has no axis_types concept (everything
-    is implicitly Auto).
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Device mesh with Auto-typed axes (what shard_map + tracing-time
+    collectives expect)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new) / ``pltpu.TPUCompilerParams`` (0.4.x)."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    """``pltpu.CompilerParams`` for a ``pallas_call``."""
+    return pltpu.CompilerParams(**kwargs)

@@ -5,6 +5,8 @@ divergence (ISS-595, Eq. in §4):  chi2(x, y) = sum_k (x_k - y_k)^2 / (x_k + y_k
 
 All pairwise forms are written to be shard- and tile-friendly: the L2 pairwise
 uses the |x|^2 - 2 x.y + |y|^2 expansion so the inner term is an MXU matmul.
+Those matmuls run at ``HIGHEST`` precision: at the default, a TPU multiplies
+f32 operands in bf16 passes, and the exact reference would rank in bf16.
 """
 from __future__ import annotations
 
@@ -84,11 +86,16 @@ def canonical_metric(name: str) -> str:
 # ---------------------------------------------------------------------------
 
 
+def _matmul_t(a: jax.Array, b: jax.Array) -> jax.Array:
+    """a @ b.T in full f32 precision on every backend."""
+    return jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+
+
 def pairwise_l2_sq(q: jax.Array, db: jax.Array) -> jax.Array:
     """(Q, d) x (N, d) -> (Q, N), via the matmul expansion (MXU-friendly)."""
     qn = jnp.sum(q * q, axis=-1)[:, None]
     dn = jnp.sum(db * db, axis=-1)[None, :]
-    cross = q @ db.T
+    cross = _matmul_t(q, db)
     out = qn - 2.0 * cross + dn
     return jnp.maximum(out, 0.0)
 
@@ -101,13 +108,13 @@ def pairwise_chi2(q: jax.Array, db: jax.Array) -> jax.Array:
 
 
 def pairwise_dot(q: jax.Array, db: jax.Array) -> jax.Array:
-    return -(q @ db.T)
+    return -_matmul_t(q, db)
 
 
 def pairwise_cosine(q: jax.Array, db: jax.Array) -> jax.Array:
     qn = q / (jnp.linalg.norm(q, axis=-1, keepdims=True) + EPS)
     dn = db / (jnp.linalg.norm(db, axis=-1, keepdims=True) + EPS)
-    return 1.0 - qn @ dn.T
+    return 1.0 - _matmul_t(qn, dn)
 
 
 PAIRWISE: dict[str, Callable[[jax.Array, jax.Array], jax.Array]] = {

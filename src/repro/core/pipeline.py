@@ -51,8 +51,9 @@ from repro.core.quantized import QuantizedDB
 from repro.core.search import mask_duplicates, merge_topk_pairs, rerank_topk
 from repro.kernels import ops
 
-# The kernels keep the (B, chunk) id matrix in SMEM; stay well under the
-# ~1 MB scalar-memory budget by default.
+# The kernels keep the (B, chunk) id matrix in SMEM.  A v5e has 1 MiB of
+# scalar memory; the compiler accepts a 512 KiB id operand and refuses
+# 1 MiB, so half of it is the budget.
 SMEM_ID_BUDGET_BYTES = 512 * 1024
 
 # Ref-mode (oracle) reranks gather a (B, chunk, d) block per chunk; bound it
@@ -72,12 +73,12 @@ def pick_rerank_chunk(b: int, m: int, d: int, chunk: int, bm: int, k: int,
         operand) — always applies;
       * the gather bound: B * chunk * d * 4 B — applies when ``mode``
         resolves to the jnp oracle, which materializes that block per chunk.
-    Never below k rounded up to a bm multiple: the per-chunk top-k needs k
-    columns to select from, matching the staged oracle for any k <= M.
+    Never below k: the per-chunk top-k needs k columns to select from,
+    matching the staged oracle for any k <= M.
     """
-    floor = -(-k // bm) * bm
     if chunk > 0:
-        return min(max(chunk, floor), m)
+        return min(max(chunk, k), m)
+    floor = -(-k // bm) * bm
     by_budget = SMEM_ID_BUDGET_BYTES // (4 * max(b, 1))
     use_pallas, _ = ops._resolve(mode)
     if not use_pallas:
@@ -97,6 +98,9 @@ def pick_rows_budget(bq: int, bm: int) -> int:
 def _stream_rerank(queries, ids, k, fold_chunk, *, d: int, chunk: int,
                    bq: int, bm: int, rows_budget: int, mode: str):
     """Chunk- and slab-stream ``fold_chunk`` over the candidate matrix.
+
+    The rerank source ``fold_chunk`` closes over is laid out once, before
+    this streamer runs (``ops.rerank_rows``), never per chunk.
 
     ``fold_chunk(q_rows, id_rows) -> (dists, ids)`` scores one (rows, c)
     id block (the fused kernel or its oracle); chunks merge through the
@@ -148,7 +152,7 @@ def _stream_rerank(queries, ids, k, fold_chunk, *, d: int, chunk: int,
 def rerank_fused(queries: jax.Array, cand_ids: jax.Array, mask: jax.Array,
                  db: jax.Array, k: int, metric: str = "l2",
                  mode: str = "auto", dedup: bool = True, chunk: int = 0,
-                 bq: int = 8, bm: int = 32, rows_budget: int = 0,
+                 bq: int = 8, bm: int = 128, rows_budget: int = 0,
                  valid: jax.Array | None = None
                  ) -> tuple[jax.Array, jax.Array]:
     """Chunk-streamed fused rerank: (B, M) candidate ids -> top-k.
@@ -167,11 +171,13 @@ def rerank_fused(queries: jax.Array, cand_ids: jax.Array, mask: jax.Array,
     if dedup:
         mask = mask_duplicates(cand_ids, mask)
     ids = jnp.where(mask, cand_ids, -1)
+    rows = ops.rerank_rows(db, mode)
 
     return _stream_rerank(
         queries, ids, k,
         lambda q_rows, id_rows: ops.fused_rerank(
-            q_rows, id_rows, db, k, metric=metric, mode=mode, bq=bq, bm=bm),
+            q_rows, id_rows, rows, k, metric=metric, mode=mode, bq=bq,
+            bm=bm),
         d=queries.shape[1], chunk=chunk, bq=bq, bm=bm,
         rows_budget=rows_budget, mode=mode)
 
@@ -182,15 +188,15 @@ def rerank_fused_quantized(queries: jax.Array, cand_ids: jax.Array,
                            mask: jax.Array, qdb: QuantizedDB, k: int,
                            expand: int = 4, metric: str = "l2",
                            mode: str = "auto", dedup: bool = True,
-                           chunk: int = 0, bq: int = 8, bm: int = 32,
+                           chunk: int = 0, bq: int = 8, bm: int = 128,
                            valid: jax.Array | None = None
                            ) -> tuple[jax.Array, jax.Array]:
     """int8-shortlist-then-fp32 rerank source for the fused pipeline.
 
     Stage 1 streams candidate chunks through the fused int8 kernel
-    (``ops.fused_rerank_int8``): d + 4 bytes DMA'd per candidate — ~4x
-    fewer HBM bytes than fp32 rows — dequantized in VMEM registers, kept
-    as a running coarse top-k' (k' = expand*k) scored under ``metric``,
+    (``ops.fused_rerank_int8``): one packed int8 row DMA'd per candidate
+    — ~3.5x fewer HBM bytes than fp32 rows — dequantized in VMEM
+    registers, kept as a running coarse top-k' (k' = expand*k) scored under ``metric``,
     so the shortlist ranks like the fp32 rerank of record (the
     quantization scheme stays L2-calibrated — DESIGN.md §11/§13).  The
     jnp dequant-gather this
@@ -213,11 +219,12 @@ def rerank_fused_quantized(queries: jax.Array, cand_ids: jax.Array,
         mask = mask_duplicates(cand_ids, mask)
     ids = jnp.where(mask, cand_ids, -1)
     kp = min(expand * k, ids.shape[1])
+    q8 = ops.rerank_rows_int8(qdb.q, mode)
 
     short_d, short_i = _stream_rerank(
         queries, ids, kp,
         lambda q_rows, id_rows: ops.fused_rerank_int8(
-            q_rows, id_rows, qdb.q, qdb.scale, kp, metric=metric, mode=mode,
+            q_rows, id_rows, q8, qdb.scale, kp, metric=metric, mode=mode,
             bq=bq, bm=bm),
         d=queries.shape[1], chunk=chunk, bq=bq, bm=bm, rows_budget=0,
         mode=mode)
@@ -285,7 +292,7 @@ def _fused_query_quantized_jit(forest: Forest, queries: jax.Array,
 def fused_query(forest: Forest, queries: jax.Array,
                 db: jax.Array | QuantizedDB, k: int, cfg: ForestConfig,
                 metric: str = "l2", dedup: bool = True, mode: str = "auto",
-                chunk: int = 0, bq: int = 8, bm: int = 32, expand: int = 4,
+                chunk: int = 0, bq: int = 8, bm: int = 128, expand: int = 4,
                 n_probes: int = 1, valid: jax.Array | None = None
                 ) -> tuple[jax.Array, jax.Array]:
     """End-to-end single-jit forest query (the production hot path).

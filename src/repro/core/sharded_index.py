@@ -34,7 +34,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
@@ -312,8 +312,12 @@ class ShardedIndex:
         pad_live[self.n_live:] = False
         self._pad_live = pad_live
         self._gids = np.asarray(gids, np.int64)
-        self._db = jnp.asarray(self._rows_host)
-        self._live = jnp.asarray(pad_live)
+        # rows and row bitmaps live where the step reads them: split over
+        # the db axes of the mesh (replicated over the tree axis), so no
+        # query re-shards them from one device
+        self._row_sharding = NamedSharding(mesh, _db_spec(self.db_axes))
+        self._db = jax.device_put(self._rows_host, self._row_sharding)
+        self._live = jax.device_put(pad_live, self._row_sharding)
         self._forest = build_sharded_index(
             index.key, self._db, index.spec.forest, mesh,
             db_axes=self.db_axes, tree_axis=tree_axis)
@@ -429,7 +433,8 @@ class ShardedIndex:
             match = self._view.filter_match_live(predicate)
             bits = np.zeros(self._pad_live.shape[0], bool)
             bits[:self.n_live] = match
-            cached = (int(np.count_nonzero(bits)), bits, jnp.asarray(bits))
+            cached = (int(np.count_nonzero(bits)), bits,
+                      jax.device_put(bits, self._row_sharding))
             self._filters[predicate] = cached
         return cached
 

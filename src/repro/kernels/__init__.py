@@ -6,16 +6,17 @@ Kernels (each <name>.py has the pl.pallas_call; ref.py has the oracle):
   * distance_topk  -- fused per-query candidate rerank + top-k (pre-gathered)
   * fused_query    -- DMA row gather + distance + running top-k in one pass
                       (the forest-query hot path; no (B, M, d) intermediate)
-  * fused_query_int8 -- the same fused pass over int8 rows + per-row scales:
-                      d + 4 bytes DMA'd per candidate, dequantized in VMEM
+  * fused_query_int8 -- the same fused pass over int8 rows (packed four to
+                      an int32 word) + per-row scales, dequantized in VMEM
                       registers (the quantized shortlist stage, DESIGN.md §11)
   * embedding_bag  -- scalar-prefetch gather + weighted segment-sum
-  * forest_traverse-- batched partition-tree descent (SMEM-resident tree,
+  * forest_traverse-- scalar partition-tree descent (SMEM-resident tree,
                       capped at SMEM_NODE_CAP nodes); n_probes > 1 adds the
-                      in-tile multi-probe expansion (DESIGN.md §9)
-  * forest_traverse_hbm -- the uncapped variant: tree arrays stay in HBM,
-                      node records fetched per level with double-buffered
-                      DMA (DESIGN.md §11); bitwise-matches the SMEM kernel
+                      multi-probe expansion (DESIGN.md §9)
+  * forest_traverse_hbm -- the uncapped variant on the query path: tree
+                      records stay in HBM, fetched per level with
+                      double-buffered DMA (DESIGN.md §11); bitwise-matches
+                      the SMEM kernel
 """
 from repro.kernels import ops, ref
 

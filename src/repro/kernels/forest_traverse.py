@@ -1,26 +1,27 @@
 """Pallas batched forest traversal (K=1 trees): query tile -> leaf ids.
 
 The paper's descent is one coordinate access + one float compare per level.
-Batched over a query tile, each level is two dynamic gathers:
-  (1) node -> (feat, thresh, child_base)   [tree arrays, scalar memory]
-  (2) per-row coordinate q[b, feat_b]      [query tile, VMEM]
+Here the whole tree and the query tile sit in scalar memory (SMEM), and the
+scalar core walks each row down the tree: per level it loads the node's
+(feat, thresh, child_base), the row's coordinate ``q[b, feat]``, compares,
+and steps to a child.  Scalar memory can only be read one scalar at a
+time, so the descent is a loop over rows and levels — no vector work, no
+DMA.
 
-``n_probes > 1`` adds the bounded multi-probe expansion of DESIGN.md §9 in
-the same tile: the primary descent records per-level projection margins in
-registers, then each alternate re-descends with the smallest-margin routing
-decision flipped — (n_probes - 1) extra fori_loops, no extra HBM traffic
-(the query tile is already resident).
+``n_probes > 1`` adds the bounded multi-probe expansion of DESIGN.md §9 per
+row: the primary descent records per-level projection margins in an SMEM
+scratch, then each alternate re-descends with the smallest-margin routing
+decision flipped (ties -> shallower depth).
 
-Tree arrays are passed as scalar-prefetch operands (SMEM-resident). This caps
-the supported tree size at the SMEM budget (~64k nodes of 12 B/node ~= 768 KB,
-``SMEM_NODE_CAP``); above the cap ``ops.traverse_tree`` dispatches to the
-HBM-resident kernel (kernels/forest_traverse_hbm.py, DESIGN.md §11), which
-fetches node records per descent level with double-buffered DMA — so the
-Pallas path now covers every tree size.  Below the cap this kernel stays the
-fast path (the whole tree is on-chip: zero per-level DMA).
+Tree arrays are passed as scalar-prefetch operands, which caps the tree at
+the scalar-memory budget (1 MiB on v5e, shared with the query tile:
+``SMEM_NODE_CAP`` nodes of 12 B).  The query path never dispatches here:
+``core.forest.traverse_forest`` always takes the HBM-resident kernel
+(kernels/forest_traverse_hbm.py, DESIGN.md §11), which has no cap;
+``ops.traverse_tree`` reaches this kernel (``kernel="smem"``, or ``"auto"``
+below the cap).  Its speed against the HBM kernel has not been measured.
 
-Grid = (B/bq,); the depth loop is a fori_loop inside the kernel so the query
-tile is read once from HBM for the whole descent.
+Grid = (B/bq,), bq = 8 rows per tile.
 """
 from __future__ import annotations
 
@@ -32,69 +33,63 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # Largest tree (allocated max_nodes) this kernel accepts: three 4-byte
-# arrays per node must fit the ~1 MB scalar memory with headroom for the
-# grid machinery.  kernels/ops.py dispatches to the HBM kernel above this.
+# arrays per node plus an (8, d) f32 query tile must fit the 1 MiB scalar
+# memory of a v5e (tests/test_tpu_compile.py compiles it at this cap).
 SMEM_NODE_CAP = 64 * 1024
+BQ = 8
 
 
-def _kernel(feat_ref, thresh_ref, child_ref, q_ref, out_ref, *,
-            max_depth: int, n_probes: int):
-    q = q_ref[...]                       # (bq, d)
-    feat = feat_ref[...]                 # (max_nodes,)
-    thresh = thresh_ref[...]
-    child = child_ref[...]
-    bq = q.shape[0]
-    node0 = jnp.zeros((bq,), jnp.int32)
+def _kernel(feat_ref, thresh_ref, child_ref, q_ref, out_ref, marg, *,
+            max_depth: int, n_probes: int, bq: int):
 
-    def descend(node):
-        """One gather+compare step: (node, margin, child-if-internal)."""
-        f = jnp.take(feat, node)                        # (bq,)
-        t = jnp.take(thresh, node)
-        cb = jnp.take(child, node)
-        xv = jnp.take_along_axis(q, f[:, None], axis=1)[:, 0]
-        go_right = xv >= t
-        internal = cb >= 0
-        margin = jnp.where(internal, jnp.abs(xv - t), jnp.inf)
-        return internal, go_right, cb, node, margin
+    def descend(b, flip, record):
+        """Walk row ``b`` to a leaf, inverting the decision at depth
+        ``flip`` (-1: none); ``record`` stores the per-level margins."""
+        def step(t, node):
+            f = feat_ref[node]
+            th = thresh_ref[node]
+            cb = child_ref[node]
+            xv = q_ref[b, f]
+            internal = cb >= 0
+            go_right = jnp.where(xv >= th, 1, 0)
+            go_right = jnp.where(t == flip, 1 - go_right, go_right)
+            if record:
+                marg[t] = jnp.where(internal, jnp.abs(xv - th), jnp.inf)
+            return jnp.where(internal, cb + go_right, node)
+        return jax.lax.fori_loop(0, max_depth, step, jnp.int32(0))
 
-    # ---- primary descent, recording per-level margins in registers -------
-    depth_col = jax.lax.broadcasted_iota(jnp.int32, (bq, max_depth), 1)
+    def row(b, _):
+        out_ref[b, 0] = descend(b, -1, True)
+        # bounded best-first expansion: flip the smallest-margin decision
+        # per alternate (strict < keeps the shallower depth on ties)
+        for p in range(1, n_probes):
+            def argmin(t, carry):
+                best, first = carry
+                m = marg[t]
+                take = m < best
+                return jnp.where(take, m, best), jnp.where(take, t, first)
+            best, first = jax.lax.fori_loop(
+                0, max_depth, argmin, (jnp.float32(jnp.inf),
+                                       jnp.int32(max_depth)))
+            found = first < max_depth
 
-    def primary_step(t, carry):
-        node, margins = carry
-        internal, go_right, cb, node, margin = descend(node)
-        margins = jnp.where(depth_col == t, margin[:, None], margins)
-        nxt = jnp.where(internal, cb + go_right.astype(jnp.int32), node)
-        return nxt, margins
+            @pl.when(found)
+            def _():
+                marg[first] = jnp.inf
+                out_ref[b, p] = descend(b, first, False)
 
-    margins0 = jnp.full((bq, max_depth), jnp.inf, jnp.float32)
-    leaf, margins = jax.lax.fori_loop(0, max_depth, primary_step,
-                                      (node0, margins0))
-    out_ref[:, 0] = leaf
+            @pl.when(jnp.logical_not(found))
+            def _():
+                out_ref[b, p] = -1
+        return 0
 
-    # ---- bounded best-first expansion: flip the smallest-margin node -----
-    # n_probes is small and static: an unrolled argmin + re-descent per
-    # alternate (ties -> shallower depth, matching traverse_multiprobe's
-    # lax.top_k ordering)
-    for p in range(1, n_probes):
-        best = jnp.min(margins, axis=1)                              # (bq,)
-        is_best = margins == best[:, None]
-        first = jnp.min(jnp.where(is_best, depth_col, max_depth), axis=1)
-        margins = jnp.where(depth_col == first[:, None], jnp.inf, margins)
-
-        def alt_step(t, node, flip=first):
-            internal, go_right, cb, node, _ = descend(node)
-            go_right = jnp.where(t == flip, ~go_right, go_right)
-            return jnp.where(internal, cb + go_right.astype(jnp.int32), node)
-
-        alt = jax.lax.fori_loop(0, max_depth, alt_step, node0)
-        out_ref[:, p] = jnp.where(jnp.isfinite(best), alt, -1)
+    jax.lax.fori_loop(0, bq, row, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("max_depth", "bq", "interpret",
+@functools.partial(jax.jit, static_argnames=("max_depth", "interpret",
                                              "n_probes"))
 def forest_traverse(feat: jax.Array, thresh: jax.Array, child_base: jax.Array,
-                    queries: jax.Array, max_depth: int, bq: int = 256,
+                    queries: jax.Array, max_depth: int,
                     interpret: bool = False, n_probes: int = 1) -> jax.Array:
     """Single K=1 tree: feat/thresh/child_base (max_nodes,), queries (B, d).
 
@@ -105,18 +100,21 @@ def forest_traverse(feat: jax.Array, thresh: jax.Array, child_base: jax.Array,
     vmap over trees for the forest.
     """
     b, d = queries.shape
-    bq = min(bq, b)
-    b_pad = -b % bq
+    b_pad = -b % BQ
     qp = jnp.pad(queries, ((0, b_pad), (0, 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,           # feat, thresh, child_base in SMEM
-        grid=((b + b_pad) // bq,),
-        in_specs=[pl.BlockSpec((bq, d), lambda i, *_: (i, 0))],
-        out_specs=pl.BlockSpec((bq, n_probes), lambda i, *_: (i, 0)),
+        grid=((b + b_pad) // BQ,),
+        in_specs=[pl.BlockSpec((BQ, d), lambda i, *_: (i, 0),
+                               memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec((BQ, n_probes), lambda i, *_: (i, 0),
+                               memory_space=pltpu.SMEM),
+        scratch_shapes=[pltpu.SMEM((max_depth,), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, max_depth=max_depth, n_probes=n_probes),
+        functools.partial(_kernel, max_depth=max_depth, n_probes=n_probes,
+                          bq=BQ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b + b_pad, n_probes), jnp.int32),
         interpret=interpret,

@@ -11,7 +11,8 @@ in HBM; per-candidate traffic drops to a single HBM read.
 
 Contract (mirrored by ``kernels.ref.fused_gather_topk_ref``):
   q (B, d) f32/bf16, ids (B, M) int32 with -1 marking invalid slots,
-  db (N, d) -> (dists (B, k) f32, ids (B, k) int32); invalid: +inf / -1.
+  db (N, d) (read as ``row_store(db)``, below) -> (dists (B, k) f32,
+  ids (B, k) int32); invalid: +inf / -1.
 
 The -1 id slot is the kernel's whole masking vocabulary, and it is load
 bearing for the segmented mutable index: tombstoned (deleted/upserted) DB
@@ -22,10 +23,19 @@ no tombstone concept.
 
 Layout: grid = (B/bq, M/bm), candidate axis innermost ("arbitrary") so the
 (bq, k) state lives in the revisited output block across the whole stream.
+The tile is (bq, bm) = (8, 128), one f32 vreg of ids and scores.
+
+Row store: the TPU copy engine moves whole (sublane, 128-lane) tiles, and a
+2-D (N, d) array in HBM is tiled (8, 128), so one row of it is not a legal
+DMA source.  The kernel therefore reads the DB as ``row_store(db)``: an
+(N, 1, dp) array, dp = d rounded up to 128 lanes, zero-padded.  Each row is
+then its own (1, dp) tile, and one DMA moves exactly one row.  The zero
+lanes add nothing to any of the four metrics.  ``core.pipeline`` builds the
+store once per rerank call, outside the chunk loop.
 
 SMEM budget: the ids operand is SMEM-resident, so B*M*4 bytes must fit the
-scalar memory (~1 MB).  ``core.pipeline`` chunk-streams the M axis to stay
-under that bound; this kernel asserts nothing and trusts its caller.
+scalar memory (1 MiB on v5e).  ``core.pipeline`` chunk-streams the M axis to
+stay under that bound; this kernel asserts nothing and trusts its caller.
 """
 from __future__ import annotations
 
@@ -40,6 +50,14 @@ from repro.compat import tpu_compiler_params
 from repro.kernels.common import POS_INF, merge_topk, select_topk_block
 
 EPS = 1e-12
+LANE = 128
+
+
+def row_store(db: jax.Array) -> jax.Array:
+    """(N, d) rows -> the kernel's (N, 1, dp) DMA-able row store."""
+    n, d = db.shape
+    dp = -(-d // LANE) * LANE
+    return jnp.pad(db, ((0, 0), (0, dp - d))).reshape(n, 1, dp)
 
 
 def _kernel(ids_smem, q_ref, ids_ref, db_ref, out_d_ref, out_i_ref,
@@ -57,10 +75,9 @@ def _kernel(ids_smem, q_ref, ids_ref, db_ref, out_d_ref, out_i_ref,
     # overlap each other and the queue keeps the HBM pipe full. Invalid
     # slots (id < 0) issue no DMA; their scores are masked to +inf below.
     def _copy(t):
-        b, jj = t // bm, t % bm
-        rid = ids_smem[i * bq + b, j * bm + jj]
+        rid = ids_smem[i * bq + t // bm, j * bm + t % bm]
         return rid, pltpu.make_async_copy(
-            db_ref.at[jnp.maximum(rid, 0)], rows.at[b, jj], sem)
+            db_ref.at[jnp.maximum(rid, 0)], rows.at[t], sem)
 
     def _start(t, _):
         rid, cp = _copy(t)
@@ -82,8 +99,9 @@ def _kernel(ids_smem, q_ref, ids_ref, db_ref, out_d_ref, out_i_ref,
     jax.lax.fori_loop(0, bq * bm, _wait, 0)
 
     # ---- score the tile ---------------------------------------------------
-    q = q_ref[...].astype(jnp.float32)[:, None, :]     # (bq, 1, d)
-    c = rows[...].astype(jnp.float32)                  # (bq, bm, d)
+    dp = rows.shape[-1]
+    q = q_ref[...].astype(jnp.float32)[:, None, :]     # (bq, 1, dp)
+    c = rows[...].astype(jnp.float32).reshape(bq, bm, dp)
     if metric == "l2":
         diff = q - c
         scores = jnp.sum(diff * diff, axis=-1)
@@ -110,36 +128,38 @@ def _kernel(ids_smem, q_ref, ids_ref, db_ref, out_d_ref, out_i_ref,
 @functools.partial(jax.jit, static_argnames=("k", "metric", "bq", "bm",
                                              "interpret"))
 def fused_gather_topk(q: jax.Array, ids: jax.Array, db: jax.Array, k: int,
-                      metric: str = "l2", bq: int = 8, bm: int = 32,
+                      metric: str = "l2", bq: int = 8, bm: int = LANE,
                       interpret: bool = False) -> tuple[jax.Array, jax.Array]:
-    """q (B, d), ids (B, M) int32 (-1 = invalid), db (N, d) -> top-k (B, k).
+    """q (B, d), ids (B, M) int32 (-1 = invalid), db -> top-k (B, k).
 
-    Never materializes the gathered ``(B, M, d)`` candidate tensor: DB rows
-    are DMA'd HBM -> VMEM tile-by-tile inside the kernel.
+    ``db`` is the (N, 1, dp) ``row_store``.  Never materializes the
+    gathered ``(B, M, d)`` candidate tensor: DB rows are DMA'd HBM -> VMEM
+    tile-by-tile inside the kernel.
     """
     b, d = q.shape
+    dp = db.shape[-1]
     m = ids.shape[1]
     bq = min(bq, max(1, b))
     bm = min(bm, m)
     b_pad = -b % bq
     m_pad = -m % bm
-    qp = jnp.pad(q, ((0, b_pad), (0, 0)))
+    qp = jnp.pad(q, ((0, b_pad), (0, dp - d)))
     idsp = jnp.pad(ids, ((0, b_pad), (0, m_pad)), constant_values=-1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                     # ids -> SMEM
         grid=((b + b_pad) // bq, (m + m_pad) // bm),
         in_specs=[
-            pl.BlockSpec((bq, d), lambda i, j, *_: (i, 0)),
+            pl.BlockSpec((bq, dp), lambda i, j, *_: (i, 0)),
             pl.BlockSpec((bq, bm), lambda i, j, *_: (i, j)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # db stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # db stays in HBM
         ],
         out_specs=[
             pl.BlockSpec((bq, k), lambda i, j, *_: (i, 0)),
             pl.BlockSpec((bq, k), lambda i, j, *_: (i, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, bm, d), db.dtype),
+            pltpu.VMEM((bq * bm, 1, dp), db.dtype),
             pltpu.SemaphoreType.DMA,
         ],
     )

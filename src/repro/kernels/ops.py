@@ -63,13 +63,30 @@ def rerank_candidates(q, cand, ids, mask, k: int, metric: str = "l2",
     return _ref.distance_topk_ref(q, cand, ids, mask, k, metric=metric)
 
 
+def rerank_rows(db, mode: Mode = "auto"):
+    """The fp32 rerank source in the layout ``mode``'s implementation
+    reads: the kernel's (N, 1, dp) row store, or the plain (N, d) rows.
+    The one place that picks the layout: callers build it once per rerank
+    and pass it to ``fused_rerank`` with the same ``mode``."""
+    use_pallas, _ = _resolve(mode)
+    return _fused.row_store(db) if use_pallas else db
+
+
+def rerank_rows_int8(q8, mode: Mode = "auto"):
+    """The int8 rows in the layout ``mode``'s implementation reads (the
+    kernel's packed (N, 1, w) int32 store, or plain (N, d) int8)."""
+    use_pallas, _ = _resolve(mode)
+    return _fused_i8.pack_int8_rows(q8) if use_pallas else q8
+
+
 def fused_rerank(q, ids, db, k: int, metric: str = "l2", mode: Mode = "auto",
-                 bq: int = 8, bm: int = 32):
+                 bq: int = 8, bm: int = 128):
     """Fused DB-row gather + distance + top-k over one candidate chunk.
 
     ids (B, M) int32 with -1 marking invalid slots.  Unlike
     ``rerank_candidates`` this takes the raw DB — the (B, M, d) gathered
-    tensor never materializes in HBM (see kernels/fused_query.py).
+    tensor never materializes in HBM (see kernels/fused_query.py).  ``db``
+    is ``rerank_rows(rows, mode)``.
     """
     use_pallas, interp = _resolve(mode)
     if use_pallas:
@@ -79,14 +96,16 @@ def fused_rerank(q, ids, db, k: int, metric: str = "l2", mode: Mode = "auto",
 
 
 def fused_rerank_int8(q, ids, q8, scale, k: int, metric: str = "l2",
-                      mode: Mode = "auto", bq: int = 8, bm: int = 32):
+                      mode: Mode = "auto", bq: int = 8, bm: int = 128):
     """Fused int8-row gather + dequantize + coarse top-k over one chunk.
 
-    ids (B, M) int32 with -1 marking invalid slots; q8 (N, d) int8 rows with
-    per-row f32 scales; ``metric`` scores the dequantized rows so the coarse
-    shortlist ranks like the fp32 rerank of record.  The Pallas kernel DMAs
-    d + 4 bytes per candidate (kernels/fused_query_int8.py); the ref branch
-    is the retired jnp dequant-gather, kept as the oracle.
+    ids (B, M) int32 with -1 marking invalid slots; q8 is
+    ``rerank_rows_int8(rows, mode)`` of (N, d) int8 rows with per-row f32
+    scales; ``metric``
+    scores the dequantized rows so the coarse shortlist ranks like the fp32
+    rerank of record.  The Pallas kernel DMAs one packed int8 row per
+    candidate (kernels/fused_query_int8.py); the ref branch is the retired
+    jnp dequant-gather, kept as the oracle.
     """
     use_pallas, interp = _resolve(mode)
     if use_pallas:
@@ -115,11 +134,12 @@ def traverse_tree(feat, thresh, child_base, queries, max_depth: int,
     absent probes) otherwise.
 
     ``kernel`` selects the Pallas variant: "smem" keeps the tree arrays in
-    scalar memory (fast, capped at ``SMEM_NODE_CAP`` allocated nodes),
-    "hbm" streams node records from HBM with double-buffered DMA (no cap,
+    scalar memory (capped at ``SMEM_NODE_CAP`` allocated nodes), "hbm"
+    streams node records from HBM with double-buffered DMA (no cap,
     DESIGN.md §11); "auto" picks by tree size — so the Pallas path never
-    falls back to jnp on large trees.  Both variants are bitwise-identical
-    to each other and to the refs.
+    falls back to jnp on large trees.  Which variant is faster on a chip
+    has not been measured.  Both variants are bitwise-identical to each
+    other and to the refs.
     """
     use_pallas, interp = _resolve(mode)
     if use_pallas:

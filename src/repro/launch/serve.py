@@ -25,10 +25,15 @@ mesh, and optional autoscaling loop; the launcher builds the fleet with
 runtime), printing any autoscaler decisions the traffic provoked:
 
   PYTHONPATH=src python -m repro.launch.serve --config fleet.yml --qps 800
+
+The process exits 1 when any request failed or timed out (a kernel fault
+inside a batch surfaces as a per-request error, never as a crash), 0
+otherwise.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
@@ -46,8 +51,17 @@ def _fmt_params(p: SearchParams) -> str:
             f"n_trees={p.n_trees or 'all'} adaptive_wave={p.adaptive_wave}")
 
 
-def _serve_fleet(args) -> None:
-    """--config path: fleet.yml -> build_fleet -> open-loop load test."""
+def _n_lost(reports: list[dict]) -> int:
+    """Requests that failed or timed out across load-test reports."""
+    lost = sum(r["n_failed"] + r["n_timeout"] for r in reports)
+    if lost:
+        print(f"[serve] FAILED: {lost} request(s) failed or timed out")
+    return lost
+
+
+def _serve_fleet(args) -> int:
+    """--config path: fleet.yml -> build_fleet -> open-loop load test.
+    Returns the number of requests that failed or timed out."""
     from repro.serve.config import build_fleet
     handle = build_fleet(args.config)
     index = handle.index
@@ -91,9 +105,12 @@ def _serve_fleet(args) -> None:
                       f"({d['reason']}, demand {d['demand_qps']:.0f} qps)")
     finally:
         handle.stop()
+    return _n_lost([r])
 
 
-def main() -> None:
+def main(argv=None) -> int:
+    """Run the launcher; returns the process exit code (1 if any request
+    failed or timed out)."""
     p = argparse.ArgumentParser()
     p.add_argument("--dataset", choices=["mnist784", "iss595"],
                    default="mnist784")
@@ -129,11 +146,12 @@ def main() -> None:
                    help="fleet.yml: config-driven stand-up (index manifest "
                         "+ serving + optional mesh/autoscale sections); "
                         "load-tests the whole fleet")
-    args = p.parse_args()
+    args = p.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.config:
-        _serve_fleet(args)
-        return
+        return 1 if _serve_fleet(args) else 0
 
     from repro.data.synthetic import iss_like, mnist_like
     if args.dataset == "mnist784":
@@ -223,6 +241,7 @@ def main() -> None:
                   f"{r['p50_ms']:.1f}ms p99 {r['p99_ms']:.1f}ms p999 "
                   f"{r['p999_ms']:.1f}ms; shed {r['shed_fraction']:.1%}; "
                   f"recall {r.get('recall_vs_oracle', float('nan')):.3f}")
+        reports = rows_out
     else:
         r = loadgen.run_open_loop(runtime, np.asarray(queries), qps,
                                   n_requests=args.requests,
@@ -234,6 +253,7 @@ def main() -> None:
               f"[{'IN' if ok else 'OUT OF'} SLO]; shed "
               f"{r['shed_fraction']:.1%}; recall "
               f"{r.get('recall_vs_oracle', float('nan')):.3f}")
+        reports = [r]
     stats = {k: v for k, v in runtime.stats().items() if k != "batcher"}
     print(f"[serve] runtime stats: {stats}")
 
@@ -244,7 +264,8 @@ def main() -> None:
     print(f"[serve] inserted id {new_id}; self-query -> id "
           f"{int(np.asarray(i)[0, 0])} dist {float(np.asarray(d)[0, 0]):.2e}")
     runtime.stop()
+    return 1 if _n_lost(reports) else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

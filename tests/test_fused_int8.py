@@ -54,7 +54,9 @@ def test_int8_kernel_matches_oracle(b, m, n, d, k):
     ids = rng.integers(0, n, size=(b, m)).astype(np.int32)
     ids[rng.uniform(size=ids.shape) < 0.15] = -1      # invalid slots
     ids = jnp.asarray(ids)
-    pd, pi = ops.fused_rerank_int8(q, ids, qdb.q, qdb.scale, k, mode="pallas")
+    pd, pi = ops.fused_rerank_int8(q, ids,
+                                   ops.rerank_rows_int8(qdb.q, "pallas"),
+                                   qdb.scale, k, mode="pallas")
     rd, ri = ref.fused_gather_topk_int8_ref(q, ids, qdb.q, qdb.scale, k)
     rd_np = np.asarray(rd)
     finite = np.isfinite(rd_np)
@@ -69,7 +71,8 @@ def test_int8_kernel_all_masked(mode):
     qdb = quantize_db(_corpus(50, 6, seed=1))
     q = _corpus(2, 6, seed=2)
     ids = jnp.full((2, 12), -1, jnp.int32)
-    d, i = ops.fused_rerank_int8(q, ids, qdb.q, qdb.scale, 3, mode=mode)
+    d, i = ops.fused_rerank_int8(q, ids, ops.rerank_rows_int8(qdb.q, mode),
+                                 qdb.scale, 3, mode=mode)
     assert np.isinf(np.asarray(d)).all()
     assert (np.asarray(i) == -1).all()
 
@@ -80,7 +83,9 @@ def test_int8_kernel_dequant_is_exact():
     qdb = quantize_db(_corpus(80, 12, seed=3))
     q = _corpus(4, 12, seed=4)
     ids = jnp.asarray(RNG.integers(0, 80, size=(4, 20)).astype(np.int32))
-    pd, pi = ops.fused_rerank_int8(q, ids, qdb.q, qdb.scale, 6, mode="pallas")
+    pd, pi = ops.fused_rerank_int8(q, ids,
+                                   ops.rerank_rows_int8(qdb.q, "pallas"),
+                                   qdb.scale, 6, mode="pallas")
     deq = (np.asarray(qdb.q).astype(np.float32)
            * np.asarray(qdb.scale)[:, None])
     want = np.sum((np.asarray(q)[:, None, :]

@@ -182,8 +182,8 @@ def test_fused_kernel_matches_oracle(b, m, n, d, metric):
     ids = rng.integers(0, n, size=(b, m)).astype(np.int32)
     ids[rng.uniform(size=ids.shape) < 0.15] = -1      # invalid slots
     pd, pi = ops.fused_rerank(jnp.asarray(q), jnp.asarray(ids),
-                              jnp.asarray(db), 5, metric=metric,
-                              mode="pallas")
+                              ops.rerank_rows(jnp.asarray(db), "pallas"), 5,
+                              metric=metric, mode="pallas")
     rd, ri = ref.fused_gather_topk_ref(jnp.asarray(q), jnp.asarray(ids),
                                        jnp.asarray(db), 5, metric=metric)
     rd_np = np.asarray(rd)

@@ -14,7 +14,8 @@ from repro.core.quantized import quantize_db
 from repro.index import IndexSpec, SearchParams, build_index
 from repro.index.tune import tune
 from repro.kernels import ref
-from repro.kernels.fused_query_int8 import fused_gather_topk_int8
+from repro.kernels.fused_query_int8 import (fused_gather_topk_int8,
+                                            pack_int8_rows)
 
 SEED = 0
 BACKENDS = ["bruteforce", "rpf", "rpf+int8", "lsh-cascade"]
@@ -110,8 +111,9 @@ def test_int8_kernel_ref_parity_per_metric(corpus, metric):
     ids[ids % 7 == 0] = -1                      # invalid slots mix in
     ids = jnp.asarray(ids)
     qj = jnp.asarray(q[:8])
-    kd, ki = fused_gather_topk_int8(qj, ids, qdb.q, qdb.scale, 10,
-                                    metric=metric, interpret=True)
+    kd, ki = fused_gather_topk_int8(qj, ids, pack_int8_rows(qdb.q),
+                                    qdb.scale, 10, metric=metric,
+                                    interpret=True)
     rd, ri = ref.fused_gather_topk_int8_ref(qj, ids, qdb.q, qdb.scale, 10,
                                             metric=metric)
     np.testing.assert_array_equal(np.asarray(ki), np.asarray(ri))

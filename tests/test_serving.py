@@ -189,6 +189,25 @@ def test_open_loop_charges_from_scheduled_time():
     assert rep["p999_ms"] >= rep["p99_ms"] >= rep["p50_ms"]
 
 
+def test_launcher_counts_failed_requests_as_lost():
+    """A kernel fault inside a batch reaches each request as an error (the
+    batcher catches it); the serving launcher must count those requests and
+    exit non-zero instead of printing n_ok/n and exiting 0."""
+    from repro.launch import serve as launcher
+
+    def fault(batch):
+        raise RuntimeError("kernel fault")
+
+    b = DynamicBatcher(fault, max_batch=4, max_wait_s=0.001).start()
+    rep = loadgen.run_open_loop(b, np.zeros((4, 2), np.float32), qps=400.0,
+                                n_requests=8, seed=0, timeout_s=10.0)
+    b.stop()
+    assert rep["n_ok"] == 0 and rep["n_failed"] == 8
+    assert launcher._n_lost([rep]) == 8
+    assert launcher._n_lost([dict(rep, n_failed=0, n_timeout=1)]) == 1
+    assert launcher._n_lost([dict(rep, n_failed=0)]) == 0
+
+
 # ---------------------------------------------------------------------------
 # batcher shutdown contract (the PR-6 stop() bug)
 # ---------------------------------------------------------------------------
