@@ -50,6 +50,7 @@ from repro.core.forest import (Forest, ForestConfig, gather_candidates,
 from repro.core.quantized import QuantizedDB
 from repro.core.search import mask_duplicates, merge_topk_pairs, rerank_topk
 from repro.kernels import ops
+from repro.tracing import DEDUP, DESCENT, RELAYOUT, RERANK
 
 # The kernels keep the (B, chunk) id matrix in SMEM.  A v5e has 1 MiB of
 # scalar memory; the compiler accepts a 512 KiB id operand and refuses
@@ -146,6 +147,16 @@ def _stream_rerank(queries, ids, k, fold_chunk, *, d: int, chunk: int,
     return dd.reshape(-1, k)[:b], ii.reshape(-1, k)[:b]
 
 
+def _union_ids(cand_ids, mask, valid, dedup: bool):
+    """(B, M) candidate ids with the dead, masked and (``dedup``) repeated
+    slots set to -1: the probed leaves' union, as the rerank reads it."""
+    if valid is not None:
+        mask = mask & valid[jnp.clip(cand_ids, 0, valid.shape[0] - 1)]
+    if dedup:
+        mask = mask_duplicates(cand_ids, mask)
+    return jnp.where(mask, cand_ids, -1)
+
+
 @functools.partial(jax.jit, static_argnames=("k", "metric", "mode", "dedup",
                                              "chunk", "bq", "bm",
                                              "rows_budget"))
@@ -166,20 +177,18 @@ def rerank_fused(queries: jax.Array, cand_ids: jax.Array, mask: jax.Array,
     into the existing id/mask path — their slots become id -1 before the
     kernel, so they issue no DMA and never occupy a top-k slot.
     """
-    if valid is not None:
-        mask = mask & valid[jnp.clip(cand_ids, 0, valid.shape[0] - 1)]
-    if dedup:
-        mask = mask_duplicates(cand_ids, mask)
-    ids = jnp.where(mask, cand_ids, -1)
-    rows = ops.rerank_rows(db, mode)
-
-    return _stream_rerank(
-        queries, ids, k,
-        lambda q_rows, id_rows: ops.fused_rerank(
-            q_rows, id_rows, rows, k, metric=metric, mode=mode, bq=bq,
-            bm=bm),
-        d=queries.shape[1], chunk=chunk, bq=bq, bm=bm,
-        rows_budget=rows_budget, mode=mode)
+    with jax.named_scope(DEDUP):
+        ids = _union_ids(cand_ids, mask, valid, dedup)
+    with jax.named_scope(RELAYOUT):
+        rows = ops.rerank_rows(db, mode)
+    with jax.named_scope(RERANK):
+        return _stream_rerank(
+            queries, ids, k,
+            lambda q_rows, id_rows: ops.fused_rerank(
+                q_rows, id_rows, rows, k, metric=metric, mode=mode, bq=bq,
+                bm=bm),
+            d=queries.shape[1], chunk=chunk, bq=bq, bm=bm,
+            rows_budget=rows_budget, mode=mode)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "expand", "metric", "mode",
@@ -213,21 +222,19 @@ def rerank_fused_quantized(queries: jax.Array, cand_ids: jax.Array,
     Matches the staged quantized oracle (core.quantized.staged_rerank_quantized)
     exactly on tie-free data.
     """
-    if valid is not None:
-        mask = mask & valid[jnp.clip(cand_ids, 0, valid.shape[0] - 1)]
-    if dedup:
-        mask = mask_duplicates(cand_ids, mask)
-    ids = jnp.where(mask, cand_ids, -1)
+    with jax.named_scope(DEDUP):
+        ids = _union_ids(cand_ids, mask, valid, dedup)
     kp = min(expand * k, ids.shape[1])
-    q8 = ops.rerank_rows_int8(qdb.q, mode)
-
-    short_d, short_i = _stream_rerank(
-        queries, ids, kp,
-        lambda q_rows, id_rows: ops.fused_rerank_int8(
-            q_rows, id_rows, q8, qdb.scale, kp, metric=metric, mode=mode,
-            bq=bq, bm=bm),
-        d=queries.shape[1], chunk=chunk, bq=bq, bm=bm, rows_budget=0,
-        mode=mode)
+    with jax.named_scope(RELAYOUT):
+        q8 = ops.rerank_rows_int8(qdb.q, mode)
+    with jax.named_scope(RERANK):
+        short_d, short_i = _stream_rerank(
+            queries, ids, kp,
+            lambda q_rows, id_rows: ops.fused_rerank_int8(
+                q_rows, id_rows, q8, qdb.scale, kp, metric=metric,
+                mode=mode, bq=bq, bm=bm),
+            d=queries.shape[1], chunk=chunk, bq=bq, bm=bm, rows_budget=0,
+            mode=mode)
     # exact fp32 rerank of the shortlist only (already deduped)
     return rerank_fused(queries, short_i, short_i >= 0, qdb.fp, k,
                         metric=metric, mode=mode, dedup=False, chunk=chunk,
@@ -248,11 +255,12 @@ def _candidates(forest: Forest, queries: jax.Array, max_depth: int,
     wider probes fold into the candidate axis of the same padded (B, M)
     id/mask contract, so nothing downstream changes.
     """
-    if n_probes <= 1:
-        leaves = traverse_forest(forest, queries, max_depth, 1, mode)
-        return gather_candidates(forest, leaves, leaf_pad)
-    leaves = traverse_forest(forest, queries, max_depth, n_probes, mode)
-    return gather_candidates_multi(forest, leaves, leaf_pad)
+    with jax.named_scope(DESCENT):
+        if n_probes <= 1:
+            leaves = traverse_forest(forest, queries, max_depth, 1, mode)
+            return gather_candidates(forest, leaves, leaf_pad)
+        leaves = traverse_forest(forest, queries, max_depth, n_probes, mode)
+        return gather_candidates_multi(forest, leaves, leaf_pad)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "max_depth", "leaf_pad",

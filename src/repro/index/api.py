@@ -60,6 +60,7 @@ from repro.checkpoint.checkpointer import Checkpointer, _flatten_with_names
 from repro.filter.metadata import MetaBlock, MetadataStore
 from repro.index.params import IndexSpec, SearchParams
 from repro.index.segments import DELTA_SID, DeltaBuffer, IndexView, SealedSegment
+from repro.tracing import INDEX_SEARCH, span
 
 _BACKENDS: dict[str, type["Index"]] = {}
 _BUILTINS_LOADED = False
@@ -346,10 +347,15 @@ class Index:
         Invalid slots: dist +inf, id -1.  Fans out over the sealed segments
         and the incremental-add delta, with tombstones masked inside the
         fused rerank; reads the published view — never the writer lock.
+        The call returns before the device finishes: its ``index.search``
+        span (``repro.tracing``) is the host's dispatch time.
         """
         if params is None and not params_kw and self._tuned_params is not None:
             params = self._tuned_params
-        return self._view.search(queries, params, **params_kw)
+        view = self._view
+        with span(INDEX_SEARCH,
+                  segments=len(view.segments) + (view.delta is not None)):
+            return view.search(queries, params, **params_kw)
 
     # ------------------------------------------------------------ mutations
     def _encode_meta_locked(self, metadata: dict | None) -> dict | None:

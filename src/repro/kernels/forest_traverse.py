@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.tracing import KERNEL_DESCENT_SMEM
+
 # Largest tree (allocated max_nodes) this kernel accepts: three 4-byte
 # arrays per node plus an (8, d) f32 query tile must fit the 1 MiB scalar
 # memory of a v5e (tests/test_tpu_compile.py compiles it at this cap).
@@ -118,5 +120,6 @@ def forest_traverse(feat: jax.Array, thresh: jax.Array, child_base: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b + b_pad, n_probes), jnp.int32),
         interpret=interpret,
+        name=KERNEL_DESCENT_SMEM,
     )(feat, thresh, child_base, qp)
     return out[:b, 0] if n_probes == 1 else out[:b]

@@ -48,6 +48,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import tpu_compiler_params
+from repro.tracing import KERNEL_DESCENT_HBM
 
 
 REC = 128           # nodes per record group: one lane tile
@@ -201,6 +202,7 @@ def forest_traverse_hbm(feat: jax.Array, thresh: jax.Array,
         compiler_params=tpu_compiler_params(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
+        name=KERNEL_DESCENT_HBM,
     )(records, qp)
     out = out[:, :b]
     return out[..., 0] if n_probes == 1 else out

@@ -44,6 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.compat import tpu_compiler_params
 from repro.kernels.common import POS_INF, merge_topk, select_topk_block
+from repro.tracing import KERNEL_RERANK_INT8
 
 EPS = 1e-12
 LANE = 128
@@ -189,6 +190,7 @@ def fused_gather_topk_int8(q: jax.Array, ids: jax.Array, q8: jax.Array,
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=KERNEL_RERANK_INT8,
     )(idsp, qp, idsp, sp, q8)
     out_d, out_i = out_d[:b], out_i[:b]
     return out_d, jnp.where(jnp.isinf(out_d), -1, out_i)

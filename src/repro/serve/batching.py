@@ -11,6 +11,9 @@ pending request fast — either way NO submitter is left hanging on an event
 that will never be set (requests that are rejected or abandoned carry an
 ``error`` that ``__call__`` re-raises).  ``stats["stopped"]`` records which
 path ran, with ``drained_on_stop`` / ``failed_on_stop`` counts.
+
+Each batch runs inside the ``serve.batch``, ``serve.collect`` and
+``serve.complete`` spans (``repro.tracing``), numbered by ``batch_no``.
 """
 from __future__ import annotations
 
@@ -20,7 +23,8 @@ import threading
 import time
 from typing import Any, Callable, Optional
 
-import numpy as np
+from repro.tracing import (SERVE_BATCH, SERVE_COLLECT, SERVE_COMPLETE,
+                           span)
 
 
 @dataclasses.dataclass
@@ -48,13 +52,12 @@ class BatcherStopped(RuntimeError):
 
 class DynamicBatcher:
     def __init__(self, serve_batch_fn: Callable[[list], list],
-                 max_batch: int = 64, max_wait_s: float = 0.005,
-                 latency_window: int = 1024):
+                 max_batch: int = 64, max_wait_s: float = 0.005):
         """serve_batch_fn: list[payload] -> list[result] (padded inside).
 
-        Latencies are kept in a fixed-size ring buffer of ``latency_window``
-        samples (bounded memory under sustained traffic); p99_latency_ms is
-        computed over that window.
+        ``stats`` counts batches, requests, the mean batch, the deepest
+        queue seen and what ``stop`` did; the batcher keeps no latencies
+        (clients time their own requests from ``Request.done_t``).
         """
         self.fn = serve_batch_fn
         self.max_batch = max_batch
@@ -64,11 +67,11 @@ class DynamicBatcher:
         self._drain = True
         self._worker = threading.Thread(target=self._loop, daemon=True)
         self.stats = {"batches": 0, "requests": 0, "mean_batch": 0.0,
-                      "p99_latency_ms": 0.0, "depth_peak": 0,
-                      "stopped": None, "drained_on_stop": 0,
+                      "depth_peak": 0, "stopped": None, "drained_on_stop": 0,
                       "failed_on_stop": 0}
-        self._latencies = np.zeros(max(1, latency_window), np.float64)
-        self._latency_count = 0      # total samples ever observed
+        # number of the batch in service (its spans' ``batch``), -1 before
+        # the first; read by serve_batch_fn on the worker thread
+        self.batch_no = -1
 
     def start(self):
         self._worker.start()
@@ -134,39 +137,37 @@ class DynamicBatcher:
             depth = self.q.qsize()
             if depth > self.stats["depth_peak"]:
                 self.stats["depth_peak"] = depth
-            batch: list[Request] = []
             try:
-                batch.append(self.q.get(timeout=0.05))
+                first = self.q.get(timeout=0.05)
             except queue.Empty:
                 continue
-            deadline = time.perf_counter() + self.max_wait_s
-            while len(batch) < self.max_batch:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
+            self.batch_no += 1
+            with span(SERVE_BATCH, batch=self.batch_no) as batch_span:
+                batch = [first]
+                with span(SERVE_COLLECT, batch=self.batch_no):
+                    deadline = time.perf_counter() + self.max_wait_s
+                    while len(batch) < self.max_batch:
+                        remaining = deadline - time.perf_counter()
+                        if remaining <= 0:
+                            break
+                        try:
+                            batch.append(self.q.get(timeout=remaining))
+                        except queue.Empty:
+                            break
+                batch_span.set_metadata(rows=len(batch),
+                                        depth=self.q.qsize())
                 try:
-                    batch.append(self.q.get(timeout=remaining))
-                except queue.Empty:
-                    break
-            try:
-                results = self.fn([r.payload for r in batch])
-            except BaseException as exc:   # noqa: BLE001 — surfaced per-req
-                for r in batch:
-                    r.finish(error=exc)
-                continue
-            window = self._latencies.shape[0]
-            for r, res in zip(batch, results):
-                r.finish(result=res)
-                self._latencies[self._latency_count % window] = \
-                    (r.done_t - r.enqueue_t) * 1e3
-                self._latency_count += 1
-            self.stats["batches"] += 1
-            self.stats["requests"] += len(batch)
-            self.stats["mean_batch"] = (self.stats["requests"]
-                                        / self.stats["batches"])
-            if self._latency_count:
-                filled = self._latencies[:min(self._latency_count, window)]
-                self.stats["p99_latency_ms"] = float(
-                    np.percentile(filled, 99))
-            if self._stop.is_set() and self._drain:
-                self.stats["drained_on_stop"] += len(batch)
+                    results = self.fn([r.payload for r in batch])
+                except BaseException as exc:  # noqa: BLE001 — surfaced per-req
+                    for r in batch:
+                        r.finish(error=exc)
+                    continue
+                with span(SERVE_COMPLETE, batch=self.batch_no):
+                    for r, res in zip(batch, results):
+                        r.finish(result=res)
+                    self.stats["batches"] += 1
+                    self.stats["requests"] += len(batch)
+                    self.stats["mean_batch"] = (self.stats["requests"]
+                                                / self.stats["batches"])
+                    if self._stop.is_set() and self._drain:
+                        self.stats["drained_on_stop"] += len(batch)

@@ -38,6 +38,7 @@ import numpy as np
 from repro.index import SearchParams, load_index
 from repro.serve import planner as planner_mod
 from repro.serve.batching import DynamicBatcher
+from repro.tracing import SERVE_ASSEMBLE, SERVE_FETCH, span
 
 __all__ = ["ServingRuntime", "build_ladder", "uniform_shard_params"]
 
@@ -248,26 +249,31 @@ class ServingRuntime:
                                      strict=True)
         self._search = self._search_sharded
 
-    def _search_local(self, q: np.ndarray, rung: int):
+    def _search_local(self, q: np.ndarray, rung: int, batch: int = -1):
         d, i = self.index.search(q, self.ladder[rung])
-        return np.asarray(d), np.asarray(i)
+        with span(SERVE_FETCH, batch=batch):
+            return np.asarray(d), np.asarray(i)
 
-    def _search_sharded(self, q: np.ndarray, rung: int):
+    def _search_sharded(self, q: np.ndarray, rung: int, batch: int = -1):
         d, i = self._sharded.search(q, self.ladder[rung])
-        return np.asarray(d), np.asarray(i)
+        with span(SERVE_FETCH, batch=batch):
+            return np.asarray(d), np.asarray(i)
 
     # ------------------------------------------------------------- serving
     def _serve_batch(self, payloads: list) -> list:
         rung = self._schedule_rung()
         n = len(payloads)
-        q = np.stack(payloads)
-        if n < self.max_batch:
-            # fixed batch shape: pad by repeating the last real query (not
-            # zeros — batch-coupled paths must not see synthetic points),
-            # slice results; one XLA compile per rung, paid at warmup
-            q = np.concatenate(
-                [q, np.repeat(q[-1:], self.max_batch - n, axis=0)])
-        dists, ids = self._search(q, rung)
+        batch = self._batcher.batch_no
+        pad = max(0, self.max_batch - n)
+        with span(SERVE_ASSEMBLE, batch=batch, pad=pad):
+            q = np.stack(payloads)
+            if pad:
+                # fixed batch shape: pad by repeating the last real query
+                # (not zeros — batch-coupled paths must not see synthetic
+                # points), slice results; one XLA compile per rung, paid
+                # at warmup
+                q = np.concatenate([q, np.repeat(q[-1:], pad, axis=0)])
+        dists, ids = self._search(q, rung, batch)
         self._counters["batches_by_rung"][rung] += 1
         self._counters["requests_total"] += n
         if rung > 0:

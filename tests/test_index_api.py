@@ -375,14 +375,22 @@ def test_serve_batch_pads_to_max_batch(corpus):
         f"expected fixed (max_batch, d) shapes, saw {seen_shapes}"
 
 
-def test_latency_ring_buffer_bounded():
+def test_batcher_counters_hold_after_100_requests():
     from repro.serve.batching import DynamicBatcher
-    b = DynamicBatcher(lambda ps: [0 for _ in ps], max_batch=4,
-                       max_wait_s=0.001, latency_window=16).start()
+    sizes = []
+
+    def serve(ps):
+        sizes.append(len(ps))
+        return [0 for _ in ps]
+
+    b = DynamicBatcher(serve, max_batch=4, max_wait_s=0.001).start()
     for _ in range(100):
         b(np.zeros(3))
     b.stop()
-    assert b._latencies.shape[0] == 16       # fixed-size ring
-    assert b._latency_count == 100
-    assert b.stats["requests"] == 100
-    assert np.isfinite(b.stats["p99_latency_ms"])
+    st = b.stats
+    assert st["requests"] == sum(sizes) == 100
+    assert st["batches"] == len(sizes) == b.batch_no + 1
+    assert st["mean_batch"] == pytest.approx(100 / len(sizes))
+    assert max(sizes) <= 4 and 0 <= st["depth_peak"] <= 4
+    assert st["stopped"] == "drained" and st["failed_on_stop"] == 0
+    assert "p99_latency_ms" not in st      # the batcher times nothing

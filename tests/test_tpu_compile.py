@@ -20,6 +20,8 @@ import pytest
 
 from repro.kernels import forest_traverse, forest_traverse_hbm, fused_query
 from repro.kernels import fused_query_int8
+from repro.tracing import (KERNEL_DESCENT_HBM, KERNEL_DESCENT_SMEM,
+                           KERNEL_RERANK, KERNEL_RERANK_INT8)
 
 B, M, N, L, NODES, DEPTH = 64, 2048, 60_000, 80, 66_730, 66
 
@@ -55,8 +57,15 @@ def _store(layout, shape, dtype):
     return out.shape, out.dtype
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, *names):
+    """The program holds a kernel, and each of ``names`` is the op name of
+    one (``pallas_call(name=...)``; the trace names the op the same)."""
+    kernel_ops = [line for line in compiled.as_text().splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernel_ops
+    for name in names:
+        assert any(line.lstrip().startswith(f"%{name}.")
+                   for line in kernel_ops), name
 
 
 @pytest.mark.parametrize("metric,d", [("l2", 784), ("chi2", 595),
@@ -69,7 +78,7 @@ def test_fused_gather_topk_compiles(one_chip, metric, d):
                                                          metric=metric),
         ((B, d), jnp.float32), ((B, M), jnp.int32),
         _store(fused_query.row_store, (N, d), jnp.float32))
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, KERNEL_RERANK)
 
 
 @pytest.mark.parametrize("d", [784, 595])
@@ -81,7 +90,7 @@ def test_fused_gather_topk_int8_compiles(one_chip, d):
         ((B, d), jnp.float32), ((B, M), jnp.int32),
         _store(fused_query_int8.pack_int8_rows, (N, d), jnp.int8),
         ((N,), jnp.float32))
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, KERNEL_RERANK_INT8)
 
 
 @pytest.mark.parametrize("n_probes", [1, 4])
@@ -92,7 +101,7 @@ def test_forest_traverse_hbm_compiles(one_chip, n_probes):
             f, t, c, q, DEPTH, n_probes=n_probes),
         ((L, NODES), jnp.int32), ((L, NODES), jnp.float32),
         ((L, NODES), jnp.int32), ((B, 784), jnp.float32))
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, KERNEL_DESCENT_HBM)
 
 
 @pytest.mark.parametrize("n_probes", [1, 4])
@@ -104,7 +113,7 @@ def test_forest_traverse_smem_compiles_at_cap(one_chip, n_probes):
             f, t, c, q, DEPTH, n_probes=n_probes),
         ((cap,), jnp.int32), ((cap,), jnp.float32), ((cap,), jnp.int32),
         ((B, 784), jnp.float32))
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, KERNEL_DESCENT_SMEM)
 
 
 @pytest.mark.parametrize("backend", ["rpf", "rpf+int8"])
@@ -143,3 +152,5 @@ def test_fused_query_pipeline_compiles(one_chip, backend, monkeypatch):
     ).lower(forest, s((8, d), jnp.float32), src).compile()
     n_kernels = compiled.as_text().count("tpu_custom_call")
     assert n_kernels >= (2 if backend == "rpf" else 3), n_kernels
+    _assert_kernel(compiled, KERNEL_DESCENT_HBM, KERNEL_RERANK,
+                   *([KERNEL_RERANK_INT8] if backend == "rpf+int8" else []))
