@@ -39,7 +39,8 @@ def adaptive_query(forest: Forest, queries: jax.Array,
                    wave: int = 10, tol: float = 0.01, metric: str = "l2",
                    mode: str = "auto", chunk: int = 0, expand: int = 4,
                    dedup: bool = True, n_probes: int = 1,
-                   valid: jax.Array | None = None):
+                   valid: jax.Array | None = None,
+                   depths: jax.Array | None = None):
     """Returns (dists, ids, trees_used). Host-side loop over tree waves.
 
     ``dedup`` masks duplicate ids within each wave's candidate set; the
@@ -48,6 +49,8 @@ def adaptive_query(forest: Forest, queries: jax.Array,
     the multi-probe leaf set (DESIGN.md §9) — early exit then trades off
     against probes as well as trees.  ``valid`` optionally masks dead DB
     rows (segment tombstones) inside every wave's fused rerank.
+    ``depths`` (L,), each tree's built depth, bounds each wave's descents
+    (``fused_query``).
     """
     n_points = db.fp.shape[0] if isinstance(db, QuantizedDB) else db.shape[0]
     cfg = cfg.resolved(n_points)
@@ -60,7 +63,9 @@ def adaptive_query(forest: Forest, queries: jax.Array,
         sub = jax.tree.map(lambda a: a[w0:w0 + wave], forest)
         d, i = fused_query(sub, queries, db, k, cfg, metric=metric, mode=mode,
                            chunk=chunk, expand=expand, dedup=dedup,
-                           n_probes=n_probes, valid=valid)
+                           n_probes=n_probes, valid=valid,
+                           depths=None if depths is None
+                           else depths[w0:w0 + wave])
         best_d, best_i = _merge_dedup(best_d, best_i, d, i, k)
         used = min(w0 + wave, n_trees)
         kth = float(jnp.mean(jnp.where(jnp.isfinite(best_d[:, -1]),

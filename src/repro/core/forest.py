@@ -711,8 +711,26 @@ def traverse_multiprobe(forest: Forest, queries: jax.Array, max_depth: int,
     return jax.vmap(one_tree)(forest)
 
 
+def _descent_kernel(mode: str, n_proj: int) -> tuple[bool, bool]:
+    """-> (the descent runs the HBM kernel, in interpret mode)."""
+    from repro.kernels import ops as _ops
+    use_pallas, interp = _ops._resolve(mode)
+    return use_pallas and n_proj == 1, interp
+
+
+def descent_levels(depths: np.ndarray, max_depth: int, n_proj: int,
+                   mode: str) -> int:
+    """Level steps of one :func:`traverse_forest` descent per tree, summed
+    over the trees: each tree's own depth (bounded by ``max_depth``) in
+    the kernel, ``max_depth`` for every tree in the XLA traversals."""
+    if _descent_kernel(mode, n_proj)[0]:
+        return int(np.minimum(depths, max_depth).sum())
+    return int(max_depth) * len(depths)
+
+
 def traverse_forest(forest: Forest, queries: jax.Array, max_depth: int,
-                    n_probes: int = 1, mode: str = "auto") -> jax.Array:
+                    n_probes: int = 1, mode: str = "auto",
+                    depths: jax.Array | None = None) -> jax.Array:
     """Mode-dispatched forest descent — the pipeline's traversal entry.
 
     Routes through the Pallas traversal kernels when the mode policy says
@@ -725,15 +743,20 @@ def traverse_forest(forest: Forest, queries: jax.Array, max_depth: int,
     :func:`traverse_multiprobe`) — on CPU ``"auto"`` resolves there, which
     keeps the historical bitwise pin of the pre-kernel graph.
 
+    ``depths`` (L,), each tree's built depth (:func:`tree_depths`), bounds
+    the kernel's per-tree descents; the XLA traversals run ``max_depth``
+    levels for every tree.  Either way the leaves are those of any bound
+    at or above the trees' depths.
+
     Returns (L, B) for ``n_probes == 1``, else (L, B, n_probes).
     """
-    from repro.kernels import ops as _ops
-    use_pallas, interp = _ops._resolve(mode)
-    if use_pallas and forest.proj_idx.shape[-1] == 1:
+    use_kernel, interp = _descent_kernel(mode, forest.proj_idx.shape[-1])
+    if use_kernel:
         from repro.kernels import forest_traverse_hbm as _hbm
         return _hbm.forest_traverse_hbm(
             forest.proj_idx[..., 0], forest.thresh, forest.child_base,
-            queries, max_depth, interpret=interp, n_probes=n_probes)
+            queries, max_depth, interpret=interp, n_probes=n_probes,
+            depths=depths)
     if n_probes == 1:
         return traverse(forest, queries, max_depth)
     return traverse_multiprobe(forest, queries, max_depth, n_probes)
@@ -823,24 +846,51 @@ def query_forest(forest: Forest, queries: jax.Array, db: jax.Array, k: int,
 # ---------------------------------------------------------------------------
 
 
+def _node_depths(child_base: np.ndarray) -> np.ndarray:
+    """(L, max_nodes) depth of every built node, -1 for the unbuilt slots.
+
+    One level-by-level frontier walk over all trees at once: a node's
+    children sit at ``child_base`` and ``child_base + 1``, so each built
+    node is touched once, and the walk takes one NumPy step per level.
+    """
+    child = np.asarray(child_base)
+    depth = np.full(child.shape, -1, np.int32)
+    tree = np.arange(child.shape[0])
+    node = np.zeros_like(tree)
+    level = 0
+    while tree.size:
+        depth[tree, node] = level
+        cb = child[tree, node]
+        inner = cb >= 0
+        tree = np.repeat(tree[inner], 2)
+        node = np.repeat(cb[inner], 2) + np.tile([0, 1], int(inner.sum()))
+        level += 1
+    return depth
+
+
+def tree_depths(forest: Forest) -> np.ndarray:
+    """(L,) int32 depth of each tree's deepest node; 0 for a single leaf.
+
+    A descent of more levels than its tree's depth changes no leaf and no
+    margin (past its leaf a node stays put and its margin is +inf), so
+    this is the exact loop bound of every traversal (DESIGN.md §11).
+    """
+    return _node_depths(forest.child_base).max(axis=1)
+
+
 def forest_stats(forest: Forest, cfg: ForestConfig, n_points: int) -> dict:
     cfg = cfg.resolved(n_points)
     child = np.asarray(forest.child_base)
     count = np.asarray(forest.leaf_count)
     n_nodes = np.asarray(forest.n_nodes)
+    depth = _node_depths(child)
+    depth_max = tree_depths(forest)
     stats = []
     for l in range(child.shape[0]):
         alive = np.arange(child.shape[1]) < n_nodes[l]
         leaf = (child[l] < 0) & alive
         occ = count[l][leaf & (count[l] > 0)]
-        # depth per node via forward sweep
-        depth = np.full(child.shape[1], -1, np.int32)
-        depth[0] = 0
-        for i in range(int(n_nodes[l])):
-            if child[l, i] >= 0:
-                depth[child[l, i]] = depth[i] + 1
-                depth[child[l, i] + 1] = depth[i] + 1
-        leaf_depths = depth[leaf & (count[l] > 0)]
+        leaf_depths = depth[l][leaf & (count[l] > 0)]
         stats.append(dict(
             n_nodes=int(n_nodes[l]),
             n_leaves=int(leaf.sum()),
@@ -848,7 +898,7 @@ def forest_stats(forest: Forest, cfg: ForestConfig, n_points: int) -> dict:
             occ_max=int(occ.max()) if occ.size else 0,
             overflow_points=int(occ[occ > cfg.capacity].sum()) if occ.size else 0,
             depth_mean=float(leaf_depths.mean()) if leaf_depths.size else 0.0,
-            depth_max=int(leaf_depths.max()) if leaf_depths.size else 0,
+            depth_max=int(depth_max[l]),
         ))
     agg = {k: float(np.mean([s[k] for s in stats])) for k in stats[0]}
     agg["per_tree"] = stats

@@ -242,7 +242,8 @@ def rerank_fused_quantized(queries: jax.Array, cand_ids: jax.Array,
 
 
 def _candidates(forest: Forest, queries: jax.Array, max_depth: int,
-                leaf_pad: int, n_probes: int, mode: str = "auto"
+                leaf_pad: int, n_probes: int, mode: str = "auto",
+                depths: jax.Array | None = None
                 ) -> tuple[jax.Array, jax.Array]:
     """Traverse + candidate slice, single- or multi-probe.
 
@@ -253,13 +254,14 @@ def _candidates(forest: Forest, queries: jax.Array, max_depth: int,
     ``"auto"`` resolves to the jnp path, so ``n_probes == 1`` still traces
     the exact pre-multi-probe graph there (the historical bitwise pin);
     wider probes fold into the candidate axis of the same padded (B, M)
-    id/mask contract, so nothing downstream changes.
+    id/mask contract, so nothing downstream changes.  ``depths`` (L,)
+    bounds each tree's descent in the kernel (``traverse_forest``).
     """
     with jax.named_scope(DESCENT):
+        leaves = traverse_forest(forest, queries, max_depth,
+                                 max(n_probes, 1), mode, depths)
         if n_probes <= 1:
-            leaves = traverse_forest(forest, queries, max_depth, 1, mode)
             return gather_candidates(forest, leaves, leaf_pad)
-        leaves = traverse_forest(forest, queries, max_depth, n_probes, mode)
         return gather_candidates_multi(forest, leaves, leaf_pad)
 
 
@@ -269,10 +271,11 @@ def _candidates(forest: Forest, queries: jax.Array, max_depth: int,
 def _fused_query_jit(forest: Forest, queries: jax.Array, db: jax.Array,
                      k: int, max_depth: int, leaf_pad: int, metric: str,
                      mode: str, dedup: bool, chunk: int, bq: int, bm: int,
-                     n_probes: int, valid: jax.Array | None
+                     n_probes: int, valid: jax.Array | None,
+                     depths: jax.Array | None = None
                      ) -> tuple[jax.Array, jax.Array]:
     cand_ids, mask = _candidates(forest, queries, max_depth, leaf_pad,
-                                 n_probes, mode)
+                                 n_probes, mode, depths)
     return rerank_fused(queries, cand_ids, mask, db, k, metric=metric,
                         mode=mode, dedup=dedup, chunk=chunk, bq=bq, bm=bm,
                         valid=valid)
@@ -287,10 +290,11 @@ def _fused_query_quantized_jit(forest: Forest, queries: jax.Array,
                                leaf_pad: int, metric: str, mode: str,
                                dedup: bool, chunk: int, bq: int, bm: int,
                                expand: int, n_probes: int,
-                               valid: jax.Array | None
+                               valid: jax.Array | None,
+                               depths: jax.Array | None = None
                                ) -> tuple[jax.Array, jax.Array]:
     cand_ids, mask = _candidates(forest, queries, max_depth, leaf_pad,
-                                 n_probes, mode)
+                                 n_probes, mode, depths)
     return rerank_fused_quantized(queries, cand_ids, mask, qdb, k,
                                   expand=expand, metric=metric, mode=mode,
                                   dedup=dedup, chunk=chunk, bq=bq, bm=bm,
@@ -301,7 +305,8 @@ def fused_query(forest: Forest, queries: jax.Array,
                 db: jax.Array | QuantizedDB, k: int, cfg: ForestConfig,
                 metric: str = "l2", dedup: bool = True, mode: str = "auto",
                 chunk: int = 0, bq: int = 8, bm: int = 128, expand: int = 4,
-                n_probes: int = 1, valid: jax.Array | None = None
+                n_probes: int = 1, valid: jax.Array | None = None,
+                depths: jax.Array | None = None
                 ) -> tuple[jax.Array, jax.Array]:
     """End-to-end single-jit forest query (the production hot path).
 
@@ -313,6 +318,8 @@ def fused_query(forest: Forest, queries: jax.Array,
     (DESIGN.md §9) — the wider candidate set rides the same (B, M) id/mask
     path, so it composes with every rerank source and with ``valid``.
     ``valid`` optionally masks dead DB rows (segment tombstones).
+    ``cfg.max_depth`` bounds every descent; ``depths`` (L,), each tree's
+    built depth, bounds each tree's descent in the kernel.
 
     Returns (dists (B, k), ids (B, k)); invalid slots: dist +inf, id -1.
     """
@@ -321,11 +328,11 @@ def fused_query(forest: Forest, queries: jax.Array,
         return _fused_query_quantized_jit(forest, queries, db, k,
                                           cfg.max_depth, cfg.leaf_pad, metric,
                                           mode, dedup, chunk, bq, bm, expand,
-                                          n_probes, valid)
+                                          n_probes, valid, depths)
     cfg = cfg.resolved(db.shape[0])
     return _fused_query_jit(forest, queries, db, k, cfg.max_depth,
                             cfg.leaf_pad, metric, mode, dedup, chunk, bq, bm,
-                            n_probes, valid)
+                            n_probes, valid, depths)
 
 
 def staged_query(forest: Forest, queries: jax.Array, db: jax.Array, k: int,

@@ -94,7 +94,8 @@ def scheduled_query(forest: Forest, queries: jax.Array,
                     db: jax.Array | QuantizedDB, k: int, cfg: ForestConfig,
                     cap: int, tol: float = 0.01, metric: str = "l2",
                     mode: str = "auto", chunk: int = 0, expand: int = 4,
-                    dedup: bool = True, valid: jax.Array | None = None
+                    dedup: bool = True, valid: jax.Array | None = None,
+                    depths: jax.Array | None = None
                     ) -> tuple[jax.Array, jax.Array, np.ndarray, np.ndarray]:
     """Convergence-gated per-query probe widening up to ``cap`` probes.
 
@@ -107,7 +108,8 @@ def scheduled_query(forest: Forest, queries: jax.Array,
     Host-side loop over rounds, like ``core.adaptive``'s wave loop; all
     array work stays on device.  ``tol = 0.0`` never converges any query,
     making the result bitwise-identical to ``fused_query`` at
-    ``n_probes = cap``.
+    ``n_probes = cap``.  ``depths`` (L,), each tree's built depth, bounds
+    every round's descents (``fused_query``).
     """
     n_points = db.fp.shape[0] if isinstance(db, QuantizedDB) else db.shape[0]
     cfg = cfg.resolved(n_points)
@@ -118,7 +120,7 @@ def scheduled_query(forest: Forest, queries: jax.Array,
     best_d, best_i = fused_query(forest, queries, db, k, cfg, metric=metric,
                                  dedup=dedup, mode=mode, chunk=chunk,
                                  expand=expand, n_probes=widths[0],
-                                 valid=valid)
+                                 valid=valid, depths=depths)
     probes_final = np.full(b, widths[0], np.int32)
     probes_processed = np.full(b, widths[0], np.int32)
     prev_kth = np.array(best_d[:, -1])      # writable host copy
@@ -136,7 +138,8 @@ def scheduled_query(forest: Forest, queries: jax.Array,
             q_act = queries[jnp.asarray(padded)]
         d, i = fused_query(forest, q_act, db, k, cfg, metric=metric,
                            dedup=dedup, mode=mode, chunk=chunk,
-                           expand=expand, n_probes=w, valid=valid)
+                           expand=expand, n_probes=w, valid=valid,
+                           depths=depths)
         d_act, i_act = d[:n_act], i[:n_act]
         if active.size == b:
             best_d, best_i = d_act, i_act

@@ -352,10 +352,12 @@ class Index:
         """
         if params is None and not params_kw and self._tuned_params is not None:
             params = self._tuned_params
+        params = params if params is not None else SearchParams(**params_kw)
         view = self._view
         with span(INDEX_SEARCH,
-                  segments=len(view.segments) + (view.delta is not None)):
-            return view.search(queries, params, **params_kw)
+                  segments=len(view.segments) + (view.delta is not None),
+                  levels=view.descent_levels(params)):
+            return view.search(queries, params)
 
     # ------------------------------------------------------------ mutations
     def _encode_meta_locked(self, metadata: dict | None) -> dict | None:

@@ -38,7 +38,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.adaptive import adaptive_query
-from repro.core.forest import Forest, ForestConfig, build_forest
+from repro.core.forest import (Forest, ForestConfig, build_forest,
+                               descent_levels, tree_depths)
 from repro.core.lsh import CascadedLSH
 from repro.core.pipeline import fused_query, rerank_fused
 from repro.core.quantized import QuantizedDB, quantize_db
@@ -75,6 +76,12 @@ class RPFEngine:
     widening of ``core.schedule`` (DESIGN.md §14); the probes each query
     actually consumed land in ``last_mean_probes`` for the tuner's
     measured-cost discount.
+
+    Every descent is bounded by the forest as built: ``depths`` holds each
+    tree's depth (``tree_depths``), the search's depth budget is the
+    prefix's deepest tree, and the descent kernel stops each tree at its
+    own depth.  Past a tree's deepest node a level changes nothing, so
+    the answers are those of the configuration's budget (DESIGN.md §11).
     """
 
     def __init__(self, spec: IndexSpec, key: jax.Array, rows: np.ndarray):
@@ -83,29 +90,46 @@ class RPFEngine:
         self.db_dev = jnp.asarray(self.db)
         self.forest = build_forest(key, self.db_dev, spec.forest,
                                    tree_chunk=spec.tree_chunk)
-        self.last_trees_used = spec.forest.n_trees
+        self._init_search_state()
+
+    def _init_search_state(self) -> None:
+        self.depths = tree_depths(self.forest)
+        self.last_trees_used = self.spec.forest.n_trees
         self.last_mean_probes = 0.0
-        self._prefix_cache: dict[int, Forest] = {}
+        self._prefix_cache: dict[int, tuple] = {}
 
     def _rerank_source(self) -> jax.Array | QuantizedDB:
         return self.db_dev
 
-    def _forest_prefix(self, n_trees: int) -> tuple[Forest, ForestConfig]:
-        """(forest, cfg) restricted to the first ``n_trees`` trees (0=all)."""
-        cfg = self.spec.forest
-        total = cfg.n_trees
-        if n_trees <= 0 or n_trees >= total:
-            return self.forest, cfg
-        if n_trees not in self._prefix_cache:
-            self._prefix_cache[n_trees] = jax.tree.map(
-                lambda a: a[:n_trees], self.forest)
-        return self._prefix_cache[n_trees], cfg._replace(n_trees=n_trees)
+    def _forest_prefix(self, n_trees: int
+                       ) -> tuple[Forest, ForestConfig, np.ndarray, jax.Array]:
+        """(forest, cfg, depths, depths on the device) restricted to the
+        first ``n_trees`` trees (0 = all); ``cfg.max_depth`` is the
+        prefix's deepest tree, within the configuration's budget."""
+        total = self.spec.forest.n_trees
+        n = total if n_trees <= 0 or n_trees >= total else n_trees
+        if n not in self._prefix_cache:
+            forest = self.forest if n == total else jax.tree.map(
+                lambda a: a[:n], self.forest)
+            depths = self.depths[:n]
+            budget = self.spec.forest.resolved(self.db.shape[0]).max_depth
+            cfg = self.spec.forest._replace(
+                n_trees=n, max_depth=min(budget, max(1, int(depths.max()))))
+            self._prefix_cache[n] = (forest, cfg, depths, jnp.asarray(depths))
+        return self._prefix_cache[n]
+
+    def descent_levels(self, params: SearchParams) -> int:
+        """Level steps one search's descents are bound to: over the trees
+        searched, each tree's loop bound times ``params.n_probes``."""
+        forest, cfg, depths, _ = self._forest_prefix(params.n_trees)
+        return params.n_probes * descent_levels(
+            depths, cfg.max_depth, forest.proj_idx.shape[-1], params.mode)
 
     def search(self, q: jax.Array, params: SearchParams,
                valid: jax.Array | None = None
                ) -> tuple[jax.Array, jax.Array]:
         src = self._rerank_source()
-        forest, cfg = self._forest_prefix(params.n_trees)
+        forest, cfg, _, depths = self._forest_prefix(params.n_trees)
         if params.probe_schedule > 0:
             # per-query convergence-gated probe widening (DESIGN.md §14);
             # violations() rejects the adaptive_wave combination upstream
@@ -113,7 +137,7 @@ class RPFEngine:
                 forest, q, src, params.k, cfg, cap=params.probe_schedule,
                 tol=params.tol, metric=params.metric, mode=params.mode,
                 chunk=params.chunk, expand=params.expand,
-                dedup=params.dedup, valid=valid)
+                dedup=params.dedup, valid=valid, depths=depths)
             self.last_trees_used = cfg.n_trees
             self.last_mean_probes = float(processed.mean())
             return d, i
@@ -123,7 +147,7 @@ class RPFEngine:
                 wave=params.adaptive_wave, tol=params.tol,
                 metric=params.metric, mode=params.mode, chunk=params.chunk,
                 expand=params.expand, dedup=params.dedup,
-                n_probes=params.n_probes, valid=valid)
+                n_probes=params.n_probes, valid=valid, depths=depths)
             self.last_trees_used = used
             self.last_mean_probes = float(params.n_probes)
             return d, i
@@ -133,7 +157,7 @@ class RPFEngine:
                            metric=params.metric, dedup=params.dedup,
                            mode=params.mode, chunk=params.chunk,
                            expand=params.expand, n_probes=params.n_probes,
-                           valid=valid)
+                           valid=valid, depths=depths)
 
     # ------------------------------------------------------------- save/load
     def state_tree(self) -> dict:
@@ -152,9 +176,7 @@ class RPFEngine:
         obj.db = np.asarray(state["db"], np.float32)
         obj.db_dev = jnp.asarray(obj.db)
         obj.forest = state["forest"]
-        obj.last_trees_used = spec.forest.n_trees
-        obj.last_mean_probes = 0.0
-        obj._prefix_cache = {}
+        obj._init_search_state()
         return obj
 
 
@@ -290,7 +312,10 @@ class RPFIndex(Index):
         return self._primary_engine.last_mean_probes
 
     def _extra_stats(self) -> dict:
-        return {"n_trees": self.spec.forest.n_trees}
+        depths = [s.engine.depths for s in self._segments]
+        return {"n_trees": self.spec.forest.n_trees,
+                "depth_max": [int(d.max()) for d in depths],
+                "depth_mean": [float(d.mean()) for d in depths]}
 
     @classmethod
     def _v1_skeleton(cls, spec: IndexSpec) -> dict:

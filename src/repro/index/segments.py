@@ -434,6 +434,14 @@ class IndexView:
     def _buffer_rows(self) -> np.ndarray:
         return self.delta._buffer._rows[:self.delta.count]
 
+    def descent_levels(self, params: SearchParams) -> int:
+        """Level steps the forest descents of one search are bound to,
+        summed over the live sealed segments (``RPFEngine.descent_levels``;
+        0 for engines without a forest)."""
+        return sum(seg.engine.descent_levels(params)
+                   for seg in self.segments
+                   if seg.n_live and hasattr(seg.engine, "descent_levels"))
+
     def search(self, queries, params: SearchParams | None = None,
                **params_kw) -> tuple[jax.Array, jax.Array]:
         """queries (B, d) or (d,) -> (dists (B, k), ids (B, k)).

@@ -33,6 +33,14 @@ Multi-probe: the primary descent records per-level margins, each
 alternate re-descends with the smallest-margin decision flipped (ties ->
 shallower depth).  Alternates re-fetch their node path from HBM.
 
+Loop bound: grid step ``l`` runs ``depths[l]`` levels per descent, the
+tree's built depth (``core.forest.tree_depths``), a scalar-prefetch
+operand read from SMEM; ``max_depth`` is static and only sizes the
+margins.  Stopping at the deepest node is exact: past it every row sits
+on a leaf, where a level step leaves the node unchanged and writes a
++inf margin over +inf, and the alternates' first-min picks a +inf column
+only when every column is +inf, which gives -1 at any bound.
+
 Bitwise contract: the float compare chain (coordinate select, ``xv >=
 thresh``, ``|xv - thresh|`` margins) is operation-for-operation
 ``ref.forest_traverse_multiprobe_ref``'s, so leaf ids match it bitwise at
@@ -70,9 +78,10 @@ def pack_records(feat: jax.Array, thresh: jax.Array,
          group(child_base)], axis=-1)
 
 
-def _kernel(rec_hbm, q_ref, out_ref, rec, nxt_v, nxt_s, sem_rec, sem_nxt,
-            *, max_depth: int, n_probes: int, bq: int):
+def _kernel(depth_ref, rec_hbm, q_ref, out_ref, rec, nxt_v, nxt_s, sem_rec,
+            sem_nxt, *, max_depth: int, n_probes: int, bq: int):
     l = pl.program_id(0)
+    depth = depth_ref[l]                   # this tree's levels (SMEM scalar)
     q = q_ref[...]                                   # (bq, d)
 
     def _record_copy(slot, b):
@@ -139,9 +148,8 @@ def _kernel(rec_hbm, q_ref, out_ref, rec, nxt_v, nxt_s, sem_rec, sem_nxt,
             return nxt, margins
 
         margins0 = jnp.full((bq, max_depth), jnp.inf, jnp.float32)
-        leaf, margins = jax.lax.fori_loop(0, max_depth, step,
-                                          (node0, margins0))
-        wait_fetch(jax.lax.rem(max_depth, 2))   # drain the trailing prefetch
+        leaf, margins = jax.lax.fori_loop(0, depth, step, (node0, margins0))
+        wait_fetch(jax.lax.rem(depth, 2))       # drain the trailing prefetch
         return leaf, margins
 
     leaf, margins = descend(jnp.full((bq, 1), -1, jnp.int32))
@@ -163,8 +171,8 @@ def _kernel(rec_hbm, q_ref, out_ref, rec, nxt_v, nxt_s, sem_rec, sem_nxt,
 def forest_traverse_hbm(feat: jax.Array, thresh: jax.Array,
                         child_base: jax.Array, queries: jax.Array,
                         max_depth: int, bq: int = 256,
-                        interpret: bool = False, n_probes: int = 1
-                        ) -> jax.Array:
+                        interpret: bool = False, n_probes: int = 1,
+                        depths: jax.Array | None = None) -> jax.Array:
     """Whole-forest descent with HBM-resident trees (no node-count cap).
 
     feat/thresh/child_base (L, max_nodes), queries (B, d).  Returns leaf
@@ -173,6 +181,11 @@ def forest_traverse_hbm(feat: jax.Array, thresh: jax.Array,
     ``core.forest.traverse_multiprobe``.  The tree axis rides the grid, so
     one pallas_call serves the forest.  The record store is packed here,
     one copy of the tree arrays per call.
+
+    ``depths`` (L,) int32, each tree's built depth
+    (``core.forest.tree_depths``), bounds each tree's descents; the
+    answer is the same as at ``max_depth`` for any bound at or above the
+    tree's depth.  Without it every tree runs ``max_depth`` levels.
     """
     n_trees = feat.shape[0]
     b, d = queries.shape
@@ -180,18 +193,18 @@ def forest_traverse_hbm(feat: jax.Array, thresh: jax.Array,
     b_pad = -b % bq
     qp = jnp.pad(queries, ((0, b_pad), (0, 0)))
     records = pack_records(feat, thresh, child_base)
+    if depths is None:
+        depths = jnp.full((n_trees,), max_depth, jnp.int32)
+    depths = jnp.minimum(depths.astype(jnp.int32), max_depth)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, max_depth=max_depth, n_probes=n_probes,
-                          bq=bq),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                 # per-tree depths -> SMEM
         grid=(n_trees, (b + b_pad) // bq),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),         # records stay in HBM
-            pl.BlockSpec((bq, d), lambda t, i: (i, 0)),
+            pl.BlockSpec((bq, d), lambda t, i, *_: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, n_probes), lambda t, i: (t, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_trees, b + b_pad, n_probes),
-                                       jnp.int32),
+        out_specs=pl.BlockSpec((1, bq, n_probes), lambda t, i, *_: (t, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, bq, 1, FIELDS * REC), jnp.int32),  # record groups
             pltpu.VMEM((bq, 1), jnp.int32),    # nxt_v: node-id bounce (VMEM)
@@ -199,11 +212,18 @@ def forest_traverse_hbm(feat: jax.Array, thresh: jax.Array,
             pltpu.SemaphoreType.DMA,           # record fetches
             pltpu.SemaphoreType.DMA,           # VMEM->SMEM bounce
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, max_depth=max_depth, n_probes=n_probes,
+                          bq=bq),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_trees, b + b_pad, n_probes),
+                                       jnp.int32),
         compiler_params=tpu_compiler_params(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name=KERNEL_DESCENT_HBM,
-    )(records, qp)
+    )(depths, records, qp)
     out = out[:, :b]
     return out[..., 0] if n_probes == 1 else out
 

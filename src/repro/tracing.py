@@ -18,7 +18,9 @@ but ``index.search`` carries the batch's number as ``batch``:
   ``pad`` is the rows of repeated query the device computes for nothing.
 * ``index.search``: ``Index.search`` until it returns, which is before the
   device finishes: the checks, the queries' copy to the device and the
-  dispatch of the query program; ``segments``, the parts it fanned out to.
+  dispatch of the query program; ``segments``, the parts it fanned out to,
+  and ``levels``, the level steps its forest descents are bound to (each
+  tree's loop bound times the probes, over the trees and segments).
 * ``serve.fetch``: the answers' copy to the host, which waits for the
   device first.
 * ``serve.complete``: handing every request its answer.

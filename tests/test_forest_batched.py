@@ -14,7 +14,7 @@ import pytest
 
 from repro.core import ForestConfig
 from repro.core.forest import (_build_forest_legacy, build_forest,
-                               forest_stats)
+                               forest_stats, tree_depths)
 
 TIED = "tied"  # heavily tied coordinates: exercises tie-escape + redraws
 
@@ -176,3 +176,43 @@ def test_impl_and_seed_mode_validation():
 # The hypothesis any-(data, config, seed) version of the bitwise invariant
 # lives in test_property.py::test_batched_builder_bitwise_invariant (that
 # module carries the optional-dependency skip).
+
+
+# ---------------------------------------------------------------------------
+# tree_depths: the built depth, the descent's exact loop bound
+# ---------------------------------------------------------------------------
+
+
+def _sweep_depths(forest) -> np.ndarray:
+    """Per-node forward sweep, one tree at a time (the oracle)."""
+    child = np.asarray(forest.child_base)
+    n_nodes = np.asarray(forest.n_nodes)
+    out = []
+    for l in range(child.shape[0]):
+        depth = np.full(child.shape[1], -1, np.int32)
+        depth[0] = 0
+        for i in range(int(n_nodes[l])):
+            if child[l, i] >= 0:
+                depth[child[l, i]] = depth[i] + 1
+                depth[child[l, i] + 1] = depth[i] + 1
+        out.append(depth.max())
+    return np.asarray(out, np.int32)
+
+
+@pytest.mark.parametrize("n,cfg_kw,impl", [
+    (700, dict(n_trees=6, capacity=12), "batched"),
+    (701, dict(n_trees=5, capacity=5, split_ratio=0.45), "legacy"),
+    (300, dict(n_trees=4, capacity=8, max_depth=3), "batched"),   # capped
+    (10, dict(n_trees=3, capacity=12), "batched"),                # N <= C
+])
+def test_tree_depths_matches_sweep(n, cfg_kw, impl):
+    x = _corpus(n, 12, seed=n)
+    cfg = ForestConfig(**cfg_kw)
+    f = build_forest(jax.random.key(n), x, cfg, impl=impl)
+    got = tree_depths(f)
+    np.testing.assert_array_equal(got, _sweep_depths(f))
+    assert got.shape == (cfg.n_trees,)
+    assert (got <= cfg.resolved(n).max_depth).all()
+    if n <= cfg.capacity:
+        assert (got == 0).all()
+    assert forest_stats(f, cfg, n)["depth_max"] == float(got.mean())

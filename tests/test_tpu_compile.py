@@ -24,6 +24,7 @@ from repro.tracing import (KERNEL_DESCENT_HBM, KERNEL_DESCENT_SMEM,
                            KERNEL_RERANK, KERNEL_RERANK_INT8)
 
 B, M, N, L, NODES, DEPTH = 64, 2048, 60_000, 80, 66_730, 66
+BUILT_DEPTH = 39      # the deepest tree of the MNIST-784 deployment's forest
 
 
 @pytest.fixture(scope="module")
@@ -105,6 +106,20 @@ def test_forest_traverse_hbm_compiles(one_chip, n_probes):
 
 
 @pytest.mark.parametrize("n_probes", [1, 4])
+def test_forest_traverse_hbm_compiles_with_depths(one_chip, n_probes):
+    """Per-tree loop bounds read from scalar memory: a trip count known
+    only when the grid step runs."""
+    compiled = _compile(
+        one_chip,
+        lambda f, t, c, q, dep: forest_traverse_hbm.forest_traverse_hbm(
+            f, t, c, q, BUILT_DEPTH, n_probes=n_probes, depths=dep),
+        ((L, NODES), jnp.int32), ((L, NODES), jnp.float32),
+        ((L, NODES), jnp.int32), ((B, 784), jnp.float32),
+        ((L,), jnp.int32))
+    _assert_kernel(compiled, KERNEL_DESCENT_HBM)
+
+
+@pytest.mark.parametrize("n_probes", [1, 4])
 def test_forest_traverse_smem_compiles_at_cap(one_chip, n_probes):
     cap = forest_traverse.SMEM_NODE_CAP
     compiled = _compile(
@@ -148,8 +163,11 @@ def test_fused_query_pipeline_compiles(one_chip, backend, monkeypatch):
         src = QuantizedDB(q=s((N, d), jnp.int8), scale=s((N,), jnp.float32),
                           fp=s((N, d), jnp.float32))
     compiled = jax.jit(
-        lambda f, q, db: pipeline.fused_query(f, q, db, 10, CONFIG)
-    ).lower(forest, s((8, d), jnp.float32), src).compile()
+        lambda f, q, db, dep: pipeline.fused_query(
+            f, q, db, 10, CONFIG._replace(max_depth=BUILT_DEPTH),
+            n_probes=4, depths=dep)
+    ).lower(forest, s((8, d), jnp.float32), src, s((L,), jnp.int32)
+            ).compile()
     n_kernels = compiled.as_text().count("tpu_custom_call")
     assert n_kernels >= (2 if backend == "rpf" else 3), n_kernels
     _assert_kernel(compiled, KERNEL_DESCENT_HBM, KERNEL_RERANK,

@@ -113,6 +113,29 @@ def test_rows_and_padding_add_up(traced):
     assert all(c["segments"] == 1 for _, _, c in spans[tracing.INDEX_SEARCH])
 
 
+def test_search_span_counts_levels(traced, index, corpus):
+    """``levels``: on the CPU the jnp descents run every tree to the
+    forest's deepest tree, below the configuration's budget."""
+    spans, _, _ = traced
+    depths = index._primary_engine.depths
+    budget = index.spec.forest.resolved(len(corpus[0])).max_depth
+    want = 2 * len(depths) * int(depths.max())
+    assert want < 2 * len(depths) * budget
+    assert all(c["levels"] == want for _, _, c in spans[tracing.INDEX_SEARCH])
+
+
+@pytest.mark.parametrize("mode,n_trees", [("pallas", 0), ("pallas", 3),
+                                          ("ref", 3)])
+def test_descent_levels_follow_the_descent(index, mode, n_trees):
+    """The kernel stops each tree at its own depth; the jnp descents at
+    the searched prefix's deepest tree."""
+    depths = index._primary_engine.depths[:n_trees or None]
+    params = SearchParams(k=5, n_probes=4, n_trees=n_trees, mode=mode)
+    per_tree = depths.sum() if mode == "pallas" \
+        else len(depths) * depths.max()
+    assert index._view.descent_levels(params) == 4 * per_tree
+
+
 def test_spans_nest_in_their_batch_in_order(traced):
     spans, _, _ = traced
     inner = (tracing.SERVE_COLLECT, tracing.SERVE_ASSEMBLE,

@@ -16,7 +16,9 @@ import numpy as np
 import pytest
 
 from repro.core import ForestConfig, build_forest
-from repro.core.forest import traverse, traverse_forest, traverse_multiprobe
+from repro.core.forest import (traverse, traverse_forest, traverse_multiprobe,
+                               tree_depths)
+from repro.index import IndexSpec, SearchParams, build_index, load_index
 from repro.kernels import ops, ref
 from repro.kernels.forest_traverse import SMEM_NODE_CAP, forest_traverse
 from repro.kernels.forest_traverse_hbm import (forest_traverse_hbm,
@@ -154,3 +156,81 @@ def test_traverse_forest_k2_falls_back():
     got = traverse_forest(f, q, rcfg.max_depth, 1, mode="pallas")
     want = traverse(f, q, rcfg.max_depth)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# the built-depth bound: per-tree loop bounds change nothing
+# ---------------------------------------------------------------------------
+
+
+def _ragged_forest():
+    """Three trees of different built depths, the last a single leaf."""
+    f, rcfg, x = _forest(n=500, d=16, n_trees=3, seed=23)
+    f = f._replace(child_base=f.child_base.at[2].set(-1))
+    depths = tree_depths(f)
+    assert depths[0] != depths[1] and depths[2] == 0, depths
+    return f, rcfg, x, depths
+
+
+@pytest.mark.parametrize("n_probes", [1, 4])
+def test_hbm_per_tree_depths_bitwise(n_probes):
+    """Each tree stopped at its own depth (and the forest's deepest as the
+    static bound) gives the budget's leaves and probe slots, bitwise."""
+    f, rcfg, x, depths = _ragged_forest()
+    q = x[:37]
+    args = (f.proj_idx[:, :, 0], f.thresh, f.child_base, q)
+    budget = forest_traverse_hbm(*args, rcfg.max_depth, interpret=True,
+                                 n_probes=n_probes)
+    per_tree = forest_traverse_hbm(*args, rcfg.max_depth, interpret=True,
+                                   n_probes=n_probes,
+                                   depths=jnp.asarray(depths))
+    tight = forest_traverse_hbm(*args, int(depths.max()), interpret=True,
+                                n_probes=n_probes,
+                                depths=jnp.asarray(depths))
+    np.testing.assert_array_equal(np.asarray(per_tree), np.asarray(budget))
+    np.testing.assert_array_equal(np.asarray(tight), np.asarray(budget))
+    if n_probes > 1:
+        assert (np.asarray(budget)[2, :, 1:] == -1).all()   # one-leaf tree
+
+
+def _at_budget(index):
+    """Re-bound every engine of ``index`` at the configuration's depth
+    budget: the descent as it ran before the built depth bounded it."""
+    for seg in index._view.segments:
+        eng = seg.engine
+        budget = eng.spec.forest.resolved(eng.db.shape[0]).max_depth
+        eng.depths = np.full_like(eng.depths, budget)
+        eng._prefix_cache.clear()
+
+
+@pytest.mark.parametrize("mode", ["auto", "pallas"])
+@pytest.mark.parametrize("backend", ["rpf", "rpf+int8"])
+def test_index_search_bounded_matches_budget(backend, mode, tmp_path):
+    """Index.search bounded by the built depth == at the depth budget,
+    dists and ids bitwise, after compact() and after save/load."""
+    rng = np.random.default_rng(29)
+    db = rng.normal(size=(300, 12)).astype(np.float32)
+    q = db[:9] + 0.05 * rng.normal(size=(9, 12)).astype(np.float32)
+    spec = IndexSpec(backend=backend,
+                     forest=ForestConfig(n_trees=4, capacity=8))
+    params = SearchParams(k=5, n_probes=3, mode=mode)
+    index = build_index(jax.random.key(3), db, spec)
+    for row in rng.normal(size=(20, 12)).astype(np.float32):
+        index.add(row)
+    index.delete(list(range(0, 30, 4)))
+    index.compact()
+    index.save(str(tmp_path / "idx"))
+    loaded = load_index(str(tmp_path / "idx"))
+    for ix in (index, loaded):
+        eng = ix._primary_engine
+        built = [tree_depths(s.engine.forest) for s in ix._view.segments]
+        assert ix.stats()["depth_max"] == [int(d.max()) for d in built]
+        assert ix.stats()["depth_mean"] == [float(d.mean()) for d in built]
+        assert eng._forest_prefix(0)[1].max_depth == int(eng.depths.max())
+        assert eng.depths.max() < spec.forest.resolved(
+            eng.db.shape[0]).max_depth
+        bounded = [np.asarray(a) for a in ix.search(q, params)]
+        _at_budget(ix)
+        budget = [np.asarray(a) for a in ix.search(q, params)]
+        np.testing.assert_array_equal(bounded[1], budget[1])
+        np.testing.assert_array_equal(bounded[0], budget[0])
